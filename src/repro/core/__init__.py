@@ -16,7 +16,8 @@ from repro.core.prefix_index import (
     chain_hashes,
 )
 from repro.core.transfer_engine import (
-    TransferEngine, TransferStats, split_blocks, merge_blocks, make_fetch,
+    PackedLayers, TransferEngine, TransferStats, make_fetch, pack_layers,
+    packed_spec, split_blocks,
 )
 from repro.core.transfer_pipeline import (
     FetchMiss, PlanDrain, PrefixFetch, ShardedPlanDrain, StepTiming,

@@ -14,11 +14,13 @@ Two cooperating mechanisms:
    buffers XLA allocates; β is enforced by the feasibility check in
    ``layer_selection``, not by hand-managed slots).
 
-2. **Host-side tier switching**: increasing α *drops* device layers (no
-   copy — the host always holds the full parameter copy, as in vLLM) and
-   donates their bytes to the KV allocator; Dynamic Reversion restores them
-   over the host link. ``TransferEngine`` does this bookkeeping and
-   accounts every byte moved (the benchmarks read these counters).
+2. **Host-side tier switching**: increasing α *drops* device layers (the
+   host copy is the only full copy, as in vLLM) and donates their bytes to
+   the KV allocator; Dynamic Reversion restores them over the host link.
+   Each switch re-places the stacks from the host copy after releasing the
+   old ones, so HBM never holds two resident stacks of one model.
+   ``TransferEngine`` does this bookkeeping and accounts every byte moved
+   (the benchmarks read these counters).
 
    Tier switches are **asynchronous**: ``submit_plan`` records the target
    and applies the free direction (drops) immediately; the layers that
@@ -38,6 +40,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import SingleDeviceSharding
 
 from repro.core.expert_remap import (
     EXPERT_PARAM_KEYS, ExpertPlan, identity_expert_plan, residency_states,
@@ -51,12 +54,79 @@ from repro.models.common import is_spec
 
 
 # ---------------------------------------------------------------------------
-# stacked-tree split / merge / fetch
+# stacked-tree split / fetch
 # ---------------------------------------------------------------------------
 
+WORD_TILE = 8 * 128     # uint32 words in one (8, 128) TPU tile
+
+
+def packed_spec(blocks):
+    """Static unpacking spec for stacked ``blocks`` (leaves [R, ...]; arrays
+    or shapes): each leaf's word offset inside one layer's buffer, padded
+    to whole tiles. Returns ((treedef, leaf specs), words per layer)."""
+    leaves, treedef = jax.tree.flatten(blocks)
+    spec, off = [], 0
+    for a in leaves:
+        nbytes = int(np.prod(a.shape[1:])) * np.dtype(a.dtype).itemsize
+        assert nbytes % 4 == 0, (a.shape, a.dtype)
+        spec.append((off, tuple(a.shape[1:]), np.dtype(a.dtype)))
+        off += -(-(nbytes // 4) // WORD_TILE) * WORD_TILE
+    return (treedef, tuple(spec)), off
+
+
+@jax.tree_util.register_pytree_node_class
+class PackedLayers:
+    """Stacked layers held as one uint32 buffer per layer, ``buf`` [m, rows,
+    128]. A cycled layer crosses host->HBM as one contiguous copy. The
+    plain 2-D tiling also keeps the TPU compiler from giving the host and
+    device sides of a leaf such as [d, Hkv, 120] different layouts, which
+    it rejects when the stacks are held leaf by leaf."""
+
+    def __init__(self, buf, spec):
+        self.buf = buf
+        self.spec = spec
+
+    def tree_flatten(self):
+        return (self.buf,), self.spec
+
+    @classmethod
+    def tree_unflatten(cls, spec, children):
+        return cls(children[0], spec)
+
+    def __len__(self) -> int:
+        return self.buf.shape[0]
+
+    def layer(self, words):
+        """One layer's [rows, 128] words (on device) -> its params tree."""
+        flat = words.reshape(-1)
+        treedef, leaves = self.spec
+        out = []
+        for off, shape, dtype in leaves:
+            n = int(np.prod(shape)) * dtype.itemsize // 4
+            out.append(jax.lax.bitcast_convert_type(
+                flat[off:off + n], dtype).reshape(shape))
+        return jax.tree.unflatten(treedef, out)
+
+
+def pack_layers(blocks, sel) -> PackedLayers:
+    """Host-side packing of layers ``sel`` of stacked ``blocks``."""
+    spec, words = packed_spec(blocks)
+    buf = np.zeros((len(sel), words), np.uint32)
+    for leaf, (off, _shape, _dt) in zip(jax.tree.leaves(blocks), spec[1]):
+        for j, c in enumerate(sel):
+            w = np.ascontiguousarray(leaf[c]).reshape(-1).view(np.uint32)
+            buf[j, off:off + w.size] = w
+    return PackedLayers(buf.reshape(len(sel), words // 128, 128), spec)
+
+
 def split_blocks(blocks, plan: RemapPlan):
-    """Split stacked layer params (leaves [R, ...]) into resident/cycle
-    stacks per ``plan``. Returns (resident, cycle, index_maps)."""
+    """Place stacked layer params (leaves [R, ...]) per ``plan``: resident
+    layers in the default device's HBM as a stacked tree, cycle layers
+    packed (``PackedLayers``) in its ``pinned_host`` memory. ``blocks`` is the
+    host copy (numpy); a resident selection of the whole stack in order
+    (the identity plan) is placed as is, so registering a tenant makes no
+    second device copy. Returns (resident, cycle, index_maps)."""
+    device = jax.devices()[0]
     res = np.array(plan.resident_layers, np.int32)
     cyc = np.array(plan.cycle_layers, np.int32)
     r_total = plan.n
@@ -66,10 +136,16 @@ def split_blocks(blocks, plan: RemapPlan):
     idx_in_stack = np.zeros(r_total, np.int32)
     idx_in_stack[res] = np.arange(len(res))
     idx_in_stack[cyc] = np.arange(len(cyc))
-    take = lambda sel: jax.tree.map(lambda a: a[sel], blocks) if len(sel) else \
-        jax.tree.map(lambda a: a[:0], blocks)
-    resident = take(res)
-    cycle = take(cyc)
+
+    on_device = SingleDeviceSharding(device, memory_kind="device")
+    if np.array_equal(res, np.arange(r_total)):
+        resident = jax.device_put(blocks, on_device)
+    else:
+        resident = jax.tree.map(
+            lambda a: jax.device_put(np.asarray(a)[res], on_device), blocks)
+    cycle = jax.device_put(
+        pack_layers(blocks, cyc),
+        SingleDeviceSharding(device, memory_kind="pinned_host"))
     maps = {
         "is_resident": jnp.asarray(is_resident),
         "idx_in_stack": jnp.asarray(idx_in_stack),
@@ -77,64 +153,34 @@ def split_blocks(blocks, plan: RemapPlan):
     return resident, cycle, maps
 
 
-def merge_blocks(resident, cycle, plan: RemapPlan):
-    """Inverse of split_blocks (used at reversion tier switches)."""
-    def merge(a_res, a_cyc):
-        shape = (plan.n,) + a_res.shape[1:]
-        out = jnp.zeros(shape, a_res.dtype)
-        if len(plan.resident_layers):
-            out = out.at[np.array(plan.resident_layers)].set(a_res)
-        if len(plan.cycle_layers):
-            out = out.at[np.array(plan.cycle_layers)].set(a_cyc)
-        return out
-    return jax.tree.map(merge, resident, cycle)
+def make_fetch(resident, cycle: PackedLayers,
+               maps: Dict[str, jax.Array]) -> Callable[[jax.Array], Any]:
+    """Build the per-repeat parameter fetch for the layer scans.
 
-
-def make_fetch(
-    resident,
-    cycle,
-    maps: Dict[str, jax.Array],
-    device_shardings=None,
-) -> Callable[[jax.Array], Any]:
-    """Build the per-repeat parameter fetch for ``LM.decode_step``.
-
-    ``device_shardings``: tree of NamedSharding(memory_kind='device') for one
-    unstacked layer — when given, host slices are explicitly device_put
-    (dry-run/TPU path); when None the index alone suffices (CPU tests).
+    Resident layers dynamic-index the device stack. A cycle layer's words
+    are dynamic-indexed out of the ``pinned_host`` buffer and
+    ``jax.device_put`` into device memory inside the traced step, on every
+    backend, then unpacked there.
     """
     is_resident = maps["is_resident"]
     idx = maps["idx_in_stack"]
-    n_cycle = jax.tree.leaves(cycle)[0].shape[0] if jax.tree.leaves(cycle) else 0
-    n_res = jax.tree.leaves(resident)[0].shape[0] if jax.tree.leaves(resident) else 0
 
-    if n_cycle == 0 or n_res == 0:      # degenerate: single-stack fetch
-        stack = resident if n_cycle == 0 else cycle
+    def from_resident(i):
+        return jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
+            resident)
 
-        def fetch_single(r):
-            return jax.tree.map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, idx[r], keepdims=False),
-                stack)
+    def from_cycle(i):
+        words = jax.lax.dynamic_index_in_dim(cycle.buf, i, keepdims=False)
+        return cycle.layer(jax.device_put(words, jax.memory.Space.Device))
 
-        return fetch_single
+    if len(cycle) == 0:                 # degenerate: single-stack fetch
+        return lambda r: from_resident(idx[r])
+    if _repeats(resident) == 0:
+        return lambda r: from_cycle(idx[r])
 
     def fetch(r):
-        i = idx[r]
-
-        def from_resident():
-            return jax.tree.map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
-                resident)
-
-        def from_cycle():
-            sl = jax.tree.map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
-                cycle)
-            if device_shardings is not None:
-                sl = jax.tree.map(
-                    lambda x, s: jax.device_put(x, s), sl, device_shardings)
-            return sl
-
-        return jax.lax.cond(is_resident[r], from_resident, from_cycle)
+        return jax.lax.cond(is_resident[r], from_resident, from_cycle, idx[r])
 
     return fetch
 
@@ -156,6 +202,11 @@ class TransferStats:
 
 class TransferEngine:
     """Owns per-model (resident, cycle) stacks + the full host copy.
+
+    The host copy (numpy, host DRAM) is the only full copy of a model's
+    stacked blocks. The device holds just the resident stack; the cycle
+    stack sits in ``pinned_host`` memory and reaches HBM one layer at a
+    time inside the step (``make_fetch``).
 
     ``plans[name]`` is always the plan the *split reflects right now* —
     the interim plan while a submitted tier switch drains, the target once
@@ -181,14 +232,24 @@ class TransferEngine:
         self._expert_cold: Dict[str, bool] = {}
 
     def register(self, name: str, blocks, layer_bytes: int) -> None:
-        self.host_copy[name] = blocks
+        """Take a model's stacked blocks. Under the identity plan the
+        caller's device stack becomes the resident stack as is; once a
+        remap drops it, its HBM is free only if the caller keeps no other
+        reference to it."""
+        # a copy, never a view: a view would keep the device buffer alive
+        self.host_copy[name] = jax.tree.map(np.array, blocks)
         self.layer_bytes[name] = layer_bytes
         self._target_alpha[name] = 0
-        self._install(name, identity_plan(_repeats(blocks)))
+        self._install(name, identity_plan(_repeats(blocks)), blocks)
 
-    def _install(self, name: str, plan: RemapPlan) -> None:
+    def _install(self, name: str, plan: RemapPlan, blocks=None) -> None:
+        """Place the stacks for ``plan`` from ``blocks`` (default: the
+        host copy). The old stacks are released first, so HBM never holds
+        two resident stacks of one model (at full width two do not fit)."""
         self.plans[name] = plan
-        self.split[name] = split_blocks(self.host_copy[name], plan)
+        self.split.pop(name, None)
+        self.split[name] = split_blocks(
+            self.host_copy[name] if blocks is None else blocks, plan)
         self._cold[name] = True
 
     def submit_plan(self, name: str, plan: RemapPlan) -> None:
@@ -244,9 +305,8 @@ class TransferEngine:
         self.submit_plan(name, plan)
         self.advance(name, float("inf"))
 
-    def fetch_for(self, name: str, device_shardings=None):
-        resident, cycle, maps = self.split[name]
-        return make_fetch(resident, cycle, maps, device_shardings)
+    def fetch_for(self, name: str):
+        return make_fetch(*self.split[name])
 
     def note_decode_step(self, name: str, t_compute_layer: float = None,
                          t_fetch_layer: float = None) -> Optional[StepTiming]:
@@ -265,10 +325,6 @@ class TransferEngine:
         self.stats.bubble_time_s += timing.bubble_time
         self.stats.decode_time_s += timing.total
         return timing
-
-    def params_with_blocks(self, params, name: str):
-        """Return params with blocks rebuilt dense (for non-remapped paths)."""
-        return dict(params, blocks=self.host_copy[name])
 
     # ------------------------------------------------------------------
     # expert-granular remapping (MoE tenants)
@@ -393,5 +449,5 @@ class TransferEngine:
 
 
 def _repeats(blocks) -> int:
-    leaf = jax.tree.leaves(blocks)[0]
-    return leaf.shape[0]
+    leaves = jax.tree.leaves(blocks)
+    return leaves[0].shape[0] if leaves else 0

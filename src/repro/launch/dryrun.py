@@ -9,8 +9,8 @@ os.environ["XLA_FLAGS"] = (
 cell on the production meshes and record memory/cost/collective analysis.
 
   PYTHONPATH=src python -m repro.launch.dryrun                 # all cells
-  PYTHONPATH=src python -m repro.launch.dryrun --arch llama3-8b \
-      --shape decode_32k --mesh multi_pod --remap-tier 0.25
+  PYTHONPATH=src python -m repro.launch.dryrun --arch whisper-medium \
+      --shape decode_32k --mesh multi_pod --remap-tier 1   # cross-KV remap
 
 Artifacts land in artifacts/dryrun/<mesh>/<arch>__<shape>[__remapX].json and
 feed benchmarks/roofline.py (EXPERIMENTS.md §Dry-run / §Roofline).
@@ -28,8 +28,6 @@ import numpy as np
 from repro.configs import (
     ARCHS, SHAPES, SHAPES_BY_NAME, get_arch, shape_applicable,
 )
-from repro.core import make_plan, uniform_interval_layers, RemapPlan
-from repro.core.transfer_engine import make_fetch, split_blocks
 from repro.distributed.analytic_cost import cost_for
 from repro.distributed.hlo_analysis import collective_bytes
 from repro.distributed.sharding import (
@@ -38,9 +36,6 @@ from repro.distributed.sharding import (
 from repro.launch.mesh import make_production_mesh
 from repro.launch.shardings import opt_shardings
 from repro.models import build_model
-from repro.models.common import (
-    Spec, is_spec, tree_abstract, tree_bytes, tree_shardings,
-)
 from repro.training import OptimizerConfig, make_optimizer, make_train_step
 
 ARTIFACT_DIR = os.path.join(
@@ -130,16 +125,17 @@ def lower_cell(arch: str, shape_name: str, mesh, *,
             lowered = jax.jit(decode_fn, donate_argnums=(1,)).lower(
                 params_abs, state_abs, tokens_abs)
             return lowered, model, shape
-        if cfg.is_encoder_decoder:
-            # beyond-paper: enc-dec models remap the immutable CROSS-KV the
-            # same way as parameters (it never changes after prefill)
-            return _lower_cross_kv_remap(
-                model, shape, mesh, rules, params_abs, state_abs, tokens_abs
-            ), model, shape
-        # MIRAGE tier: uniform-interval split, cycle stack in pinned_host
-        return _lower_remap_decode(
-            model, shape, mesh, rules, params_abs, state_abs, tokens_abs,
-            remap_tier), model, shape
+        if not cfg.is_encoder_decoder:
+            # the layer remap step is the serving engine's own
+            # (serving.engine.make_decode), compiled for the chip by
+            # tests/test_tpu_compile.py
+            raise ValueError("--remap-tier lowers the enc-dec cross-KV "
+                             "remap only")
+        # beyond-paper: enc-dec models remap the immutable CROSS-KV the
+        # same way as parameters (it never changes after prefill)
+        return _lower_cross_kv_remap(
+            model, shape, mesh, rules, params_abs, state_abs, tokens_abs
+        ), model, shape
 
 
 def _lower_cross_kv_remap(model, shape, mesh, rules, params_abs, state_abs,
@@ -187,68 +183,6 @@ def _lower_cross_kv_remap(model, shape, mesh, rules, params_abs, state_abs,
         "alpha": 0, "m": 0,
         "cycle_bytes_per_device": cross_bytes,
         "resident_bytes_per_device": 0,
-    }
-    return lowered
-
-
-def _lower_remap_decode(model, shape, mesh, rules, params_abs, state_abs,
-                        tokens_abs, tier: float):
-    repeats = model.repeats
-    alpha = max(int(round(tier * repeats)), 1)
-    plan = make_plan(repeats, alpha, t_c=1.0, t_t=1e-9, double_buffer=True)
-    blocks_specs = model.specs()["blocks"]
-
-    cyc = np.array(plan.cycle_layers, np.int32)
-    res = np.array(plan.resident_layers, np.int32)
-
-    def take_abs(spec_tree, sel, memory_kind=None):
-        def f(s: Spec):
-            shp = (len(sel),) + s.shape[1:]
-            sh = sharding_for(s.logical, shp, mesh, rules, memory_kind)
-            return jax.ShapeDtypeStruct(shp, s.dtype, sharding=sh)
-        return jax.tree.map(f, spec_tree, is_leaf=is_spec)
-
-    resident_abs = take_abs(blocks_specs, res)
-    cycle_abs = take_abs(blocks_specs, cyc, memory_kind="pinned_host")
-    # per-layer device shardings for the in-step device_put (one unstacked layer)
-    layer_specs = jax.tree.map(
-        lambda s: Spec(s.shape[1:], s.logical[1:], s.dtype),
-        blocks_specs, is_leaf=is_spec)
-    dev_sh = tree_shardings(layer_specs, mesh, rules, memory_kind="device")
-
-    is_res = np.zeros(repeats, bool)
-    is_res[res] = True
-    idx = np.zeros(repeats, np.int32)
-    idx[res] = np.arange(len(res))
-    idx[cyc] = np.arange(len(cyc))
-    maps = {"is_resident": jnp.asarray(is_res), "idx_in_stack": jnp.asarray(idx)}
-
-    head_abs = {k: v for k, v in params_abs.items() if k != "blocks"}
-
-    def decode_fn(head, resident, cycle, state, tokens):
-        fetch = make_fetch(resident, cycle, maps, device_shardings=dev_sh)
-        params = dict(head, blocks=None)
-        return model.impl.decode_step(
-            params, state, tokens, shape.seq_len, fetch=fetch)
-
-    lowered = jax.jit(decode_fn, donate_argnums=(3,)).lower(
-        head_abs, resident_abs, cycle_abs, state_abs, tokens_abs)
-    # CPU memory_analysis cannot attribute host space; record the exact
-    # host-resident (pinned_host cycle stack) bytes analytically so the
-    # roofline can subtract them from device bytes (TPU would report them
-    # under host_argument_size_in_bytes).
-    def per_dev_bytes(abs_tree):
-        total = 0
-        for a in jax.tree.leaves(abs_tree):
-            local = a.sharding.shard_shape(a.shape)
-            total += int(np.prod(local)) * a.dtype.itemsize
-        return total
-
-    lowered._mirage_extras = {           # picked up by analyze()
-        "alpha": alpha,
-        "m": plan.m,
-        "cycle_bytes_per_device": per_dev_bytes(cycle_abs),
-        "resident_bytes_per_device": per_dev_bytes(resident_abs),
     }
     return lowered
 
@@ -346,7 +280,8 @@ def main():
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)
     ap.add_argument("--mesh", default=None, choices=[None, "single_pod", "multi_pod"])
-    ap.add_argument("--remap-tier", type=float, default=0.0)
+    ap.add_argument("--remap-tier", type=float, default=0.0,
+                    help="> 0: enc-dec cross-KV held in pinned_host")
     ap.add_argument("--microbatches", type=int, default=0,
                     help="0 = auto (carry-budget heuristic)")
     ap.add_argument("--remat", default="full")

@@ -15,13 +15,9 @@ import jax
 
 
 def make_auto_mesh(shape, axes):
-    """jax.make_mesh with all-Auto axis types, portable across jax versions
-    (jax.sharding.AxisType landed after 0.4.x; older releases default every
-    mesh axis to Auto, which is exactly what we ask for on newer ones)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis of type Auto."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

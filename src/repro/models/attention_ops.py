@@ -277,13 +277,8 @@ def distributed_decode_attention(
     b_spec = P(batch_axes or None)
     in_specs = (
         b_spec, kv_seq_spec, kv_seq_spec, b_spec, kv_seq_spec, kv_seq_spec)
-    if hasattr(jax, "shard_map"):
-        mapped = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
-                               out_specs=b_spec, check_vma=False)
-    else:  # jax <= 0.4.x spelling
-        from jax.experimental.shard_map import shard_map as _shard_map
-        mapped = _shard_map(local, mesh=mesh, in_specs=in_specs,
-                            out_specs=b_spec, check_rep=False)
+    mapped = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                           out_specs=b_spec, check_vma=False)
     out = mapped(q, k_cache, v_cache, q_pos, kv_pos, kv_valid)
     b, _, hkv, g, d = out.shape
     return out.reshape(b, hkv * g, d)

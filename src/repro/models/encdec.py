@@ -92,13 +92,17 @@ class EncDec:
         x = params["embed"][tokens].astype(dtype_of(self.cfg))
         return x * (self.cfg.d_model ** 0.5)
 
-    def dec_seq(self, params, x, enc_out, remat_policy=None, collect_cache=False):
+    def dec_seq(self, params, x, enc_out, remat_policy=None, collect_cache=False,
+                fetch=None):
+        """Decoder stack over ``params['blocks']``, or over the repeat index
+        with ``fetch(r)`` supplying each layer (see ``LM.fwd_seq``)."""
         cfg = self.cfg
         b, s, _ = x.shape
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
         ctx = {"positions": positions, "enc_out": enc_out}
 
-        def body(x, p):
+        def body(x, xs):
+            (p,) = xs if fetch is None else fetch(xs)
             h, self_cache = _SELF.fwd_seq(
                 p["self"], rms_norm(x, p["norm1"], cfg.norm_eps), ctx, cfg)
             x = x + h
@@ -112,7 +116,8 @@ class EncDec:
         if remat_policy and remat_policy != "none":
             body = jax.checkpoint(body) if remat_policy == "full" else jax.checkpoint(
                 body, policy=jax.checkpoint_policies.checkpoint_dots)
-        x, caches = jax.lax.scan(body, x, params["blocks"][0])
+        xs = params["blocks"] if fetch is None else jnp.arange(self.repeats)
+        x, caches = jax.lax.scan(body, x, xs)
         return x, caches
 
     def loss(self, params, frames, tokens, targets, mask):
@@ -155,13 +160,14 @@ class EncDec:
             lambda s: s.materialize(None),
             self.decode_state_specs(batch, max_context), is_leaf=is_spec)
 
-    def prefill(self, params, frames, tokens, max_context: int):
+    def prefill(self, params, frames, tokens, max_context: int, fetch=None):
         """Encode source, teacher-force prompt tokens, build decode state."""
         cfg = self.cfg
         b, s = tokens.shape
         enc_out = self.encode(params, frames)
         x = self.embed(params, tokens)
-        x, caches = self.dec_seq(params, x, enc_out, collect_cache=True)
+        x, caches = self.dec_seq(params, x, enc_out, collect_cache=True,
+                                 fetch=fetch)
         h = rms_norm(x[:, -1], params["final_norm"], cfg.norm_eps)
         logits = _einsum("bd,dv->bv", h, params["out"])
         pad = max_context - s

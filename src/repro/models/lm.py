@@ -161,8 +161,13 @@ class LM:
 
     # ------------------------------------------------------------ seq forward
     def fwd_seq(self, params, x, ctx, remat_policy: Optional[str] = None,
-                collect_cache: bool = False):
-        """x [B,S,D] -> (x, aux_loss, caches tuple-of-stacked | None)."""
+                collect_cache: bool = False,
+                fetch: Optional[Callable[[jax.Array], Any]] = None):
+        """x [B,S,D] -> (x, aux_loss, caches tuple-of-stacked | None).
+
+        Scans ``params['blocks']``, or, with ``fetch``, the repeat index
+        with ``fetch(r)`` supplying each repeat's layer params (serving
+        with split resident/host stacks; see ``decode_step``)."""
         cfg = self.cfg
 
         inner_remat = bool(remat_policy and remat_policy != "none") \
@@ -183,8 +188,9 @@ class LM:
                 x = x + h2
             return with_sharding_constraint(x, ("batch", None, None)), cache, a
 
-        def body(carry, layer_params):
+        def body(carry, xs):
             x, aux = carry
+            layer_params = xs if fetch is None else fetch(xs)
             caches = []
             for ld, p in zip(self.pattern, layer_params):
                 fn = partial(apply_layer, ld)
@@ -204,8 +210,9 @@ class LM:
             else:
                 body = jax.checkpoint(
                     body, policy=jax.checkpoint_policies.checkpoint_dots)
+        xs = params["blocks"] if fetch is None else jnp.arange(self.repeats)
         (x, aux), caches = jax.lax.scan(
-            body, (x, jnp.zeros((), jnp.float32)), params["blocks"])
+            body, (x, jnp.zeros((), jnp.float32)), xs)
         return x, aux, caches
 
     # ------------------------------------------------------- decode state mgmt
@@ -495,14 +502,15 @@ class LM:
 
     # --------------------------------------------------------------- prefill
     def prefill(self, params, tokens, max_context: int, prefix_embeds=None,
-                lengths=None):
+                lengths=None, fetch=None):
         """Returns (last_logits [B,V], decode_state)."""
         b, s_tok = tokens.shape
         x = self.embed(params, tokens, prefix_embeds)
         s = x.shape[1]
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
         ctx = {"positions": positions}
-        x, _aux, caches = self.fwd_seq(params, x, ctx, collect_cache=True)
+        x, _aux, caches = self.fwd_seq(params, x, ctx, collect_cache=True,
+                                       fetch=fetch)
         if lengths is None:
             lengths = jnp.full((b,), s, jnp.int32)
         last = jnp.clip(lengths - 1, 0, s - 1)

@@ -57,14 +57,15 @@ class Model:
         return loss + 0.01 * aux
 
     # ---------------------------------------------------------------- serving
-    def prefill(self, params, batch, max_context: int):
+    def prefill(self, params, batch, max_context: int, fetch=None):
         if self.cfg.is_encoder_decoder:
             return self.impl.prefill(
-                params, batch["frames"], batch["tokens"], max_context)
+                params, batch["frames"], batch["tokens"], max_context,
+                fetch=fetch)
         return self.impl.prefill(
             params, batch["tokens"], max_context,
             prefix_embeds=batch.get("patch_embeds"),
-            lengths=batch.get("lengths"))
+            lengths=batch.get("lengths"), fetch=fetch)
 
     def decode_step(self, params, state, tokens, max_context: int, fetch=None):
         return self.impl.decode_step(params, state, tokens, max_context, fetch=fetch)

@@ -34,12 +34,12 @@ from repro.configs.base import ModelConfig, RuntimeConfig
 from repro.core import (
     ControllerConfig, MetadataStore, MemoryInfo, ModelInfo,
     PagedKVAllocator, PrefixIndex, RemapDecision, RemappingController,
-    TransferEngine, identity_plan,
+    TransferEngine, identity_plan, make_fetch,
 )
 from repro.models import build_model
 from repro.models.common import tree_bytes
 from repro.serving.hw import HardwareSpec, TPU_V5E
-from repro.serving.perf_model import PerfModel
+from repro.serving.perf_model import PerfModel, kv_bytes_per_token
 from repro.serving.request import (
     DECODE_WATERMARK_TOKENS, Request, ServingMetrics,
 )
@@ -47,6 +47,20 @@ from repro.serving.scheduler import admission_watermark, make_scheduler
 from repro.serving.slo import (
     SLOSpec, preemption_victim, runtime_tenant_slack, tier_rank,
 )
+
+
+def make_decode(model, paged: bool, max_context: int):
+    """One decode step over a tenant's ``(resident, cycle, maps)`` split:
+    every layer comes through ``make_fetch``, so cycle layers cross from
+    ``pinned_host`` into HBM inside the step. Params hold no blocks."""
+    def step(params, split, state, tokens):
+        fetch = make_fetch(*split)
+        if paged:
+            return model.impl.decode_step_paged(
+                params, state, tokens, fetch=fetch)
+        return model.decode_step(params, state, tokens, max_context,
+                                 fetch=fetch)
+    return step
 
 
 def execute_remap_decision(allocator, store, elastic_pages, d, *,
@@ -105,13 +119,14 @@ class TenantConfig:
 
 
 class Tenant:
-    """Runtime state for one hosted model."""
+    """Runtime state for one hosted model. ``params`` holds everything but
+    the stacked blocks, which the engine's ``TransferEngine`` owns."""
 
     def __init__(self, name: str, tc: TenantConfig, hw: HardwareSpec):
         self.name = name
         self.cfg = tc.cfg
         self.model = build_model(tc.cfg)
-        self.params = tc.params
+        self.params = {k: v for k, v in tc.params.items() if k != "blocks"}
         self.max_batch = tc.max_batch
         self.max_context = tc.max_context
         self.perf = PerfModel(tc.cfg, hw)
@@ -120,8 +135,8 @@ class Tenant:
         self.paged = tc.paged
         self.state = None if tc.paged else \
             self.model.init_decode_state(tc.max_batch, tc.max_context)
-        self._decode_jit: Dict[Tuple[int, ...], Any] = {}
-        self._prefill_jit = None
+        self.decode = jax.jit(
+            make_decode(self.model, tc.paged, tc.max_context))
 
     def init_paged_state(self, total_pages: int, page_size: int):
         """Pool covers every allocator page id + one scratch page (used by
@@ -246,8 +261,15 @@ class ServingEngine:
             s != SLOSpec() for s in self.slo_specs.values())
         self.tenants = {n: Tenant(n, tc, hw) for n, tc in tenants.items()}
         self.allocator = PagedKVAllocator(base_kv_pages, page_size)
+        # HBM one allocator page id costs: every paged tenant holds a pool
+        # over every page id (with none, the KV a page of tokens would
+        # take in the dense tenants' caches)
+        kv_owners = [t for t in self.tenants.values() if t.paged] \
+            or list(self.tenants.values())
+        page_bytes = page_size * sum(
+            kv_bytes_per_token(t.cfg, t.cfg.dtype_bytes) for t in kv_owners)
         self.store = MetadataStore(MemoryInfo(
-            hbm_bytes=hw.hbm_bytes, page_bytes=page_size * 1024,
+            hbm_bytes=hw.hbm_bytes, page_bytes=max(page_bytes, 1),
             base_kv_pages=base_kv_pages))
         self.xfer = TransferEngine()
         for n, t in self.tenants.items():
@@ -258,7 +280,7 @@ class ServingEngine:
                 priority=tenants[n].priority,
                 max_remap_fraction=runtime.max_remap_fraction,
                 slo_tier=tenants[n].slo.tier))
-            self.xfer.register(n, t.params["blocks"], unit_bytes)
+            self.xfer.register(n, tenants[n].params["blocks"], unit_bytes)
         self.controller = RemappingController(
             self.store,
             ControllerConfig(
@@ -772,7 +794,9 @@ class ServingEngine:
         if t.paged:
             logits = self._prefill_paged(t, r, slot, batch)
         else:
-            logits, state1 = t.model.prefill(t.params, batch, t.max_context)
+            logits, state1 = t.model.prefill(
+                t.params, batch, t.max_context,
+                fetch=self.xfer.fetch_for(t.name))
         tok = int(jnp.argmax(logits[0]))
         t.slots[slot] = r
         r.slot = slot
@@ -799,7 +823,8 @@ class ServingEngine:
         b, s = prompt.shape[0], x.shape[1]
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
         xo, _, caches = lm.fwd_seq(t.params, x, {"positions": positions},
-                                   collect_cache=True)
+                                   collect_cache=True,
+                                   fetch=self.xfer.fetch_for(t.name))
         logits = lm.logits_last(t.params, xo[:, -1])
         pages = self.allocator.seq_pages[r.rid]
         page_size = self.allocator.page_size
@@ -862,7 +887,8 @@ class ServingEngine:
             tokens = jnp.asarray(
                 r.prompt[r.prefill_pos:r.prefill_pos + chunk])
             logits, t.state = t.model.impl.prefill_chunk_paged(
-                t.params, t.state, r.slot, tokens, r.prefill_pos)
+                t.params, t.state, r.slot, tokens, r.prefill_pos,
+                fetch=self.xfer.fetch_for(t.name))
             r.prefill_pos += chunk
             spent += chunk
             if r.prefill_pos >= r.prompt_len:
@@ -946,13 +972,11 @@ class ServingEngine:
         # the interim plan mid-drain keeps pending layers in the cycle set,
         # so the remapped fetch path stays consistent through a tier switch
         plan = self.xfer.plans[t.name]
-        remapped = plan.m > 0
         batch = len(reqs)
         avg_ctx = sum(r.total_len for r in reqs) / batch
-        if remapped:
-            resident, cycle, maps = self.xfer.split[t.name]
-            logits, t.state = self._decode_fn(t, remapped=True)(
-                t.params, resident, cycle, maps, t.state, jnp.asarray(tokens))
+        logits, t.state = t.decode(
+            t.params, self.xfer.split[t.name], t.state, jnp.asarray(tokens))
+        if plan.m > 0:
             # shared-pipeline bubble accounting (same event model and
             # inputs the simulator charges for this plan)
             t_c_layer, t_f_layer = t.perf.pipeline_inputs(
@@ -964,8 +988,6 @@ class ServingEngine:
             # simulator's denominator
             self.xfer.stats.decode_time_s += \
                 t.perf.decode_step_time(batch, avg_ctx)
-            logits, t.state = self._decode_fn(t)(
-                t.params, t.state, jnp.asarray(tokens))
         choices = np.asarray(jnp.argmax(logits, axis=-1))
         for r in list(reqs):
             r.generated.append(int(choices[r.slot]))
@@ -974,34 +996,6 @@ class ServingEngine:
                     r.total_len >= t.max_context - 1:
                 self._finish(t, r)
         return pressure
-
-    def _decode_fn(self, t: Tenant, remapped: bool = False):
-        """jit cache keyed by split shapes; param stacks are jit *arguments*
-        (never closure constants) so one executable serves every plan with
-        the same (resident, cycle) sizes."""
-        plan = self.xfer.plans[t.name]
-        key = (len(plan.resident_layers) if remapped else t.model.repeats,
-               len(plan.cycle_layers) if remapped else 0, t.paged)
-        if key not in t._decode_jit:
-            if remapped:
-                from repro.core.transfer_engine import make_fetch
-
-                def fn(params, resident, cycle, maps, state, tokens):
-                    fetch = make_fetch(resident, cycle, maps)
-                    if t.paged:
-                        return t.model.impl.decode_step_paged(
-                            params, state, tokens, fetch=fetch)
-                    return t.model.decode_step(
-                        params, state, tokens, t.max_context, fetch=fetch)
-            else:
-                def fn(params, state, tokens):
-                    if t.paged:
-                        return t.model.impl.decode_step_paged(
-                            params, state, tokens)
-                    return t.model.decode_step(
-                        params, state, tokens, t.max_context)
-            t._decode_jit[key] = jax.jit(fn)
-        return t._decode_jit[key]
 
     # ------------------------------------------------------------ preemption
     def _preempt_one(self, exclude: str = "") -> bool:

@@ -110,3 +110,14 @@ A100_PCIE = HardwareSpec(
 
 SPECS = {s.name: s for s in (TPU_V5E, TPU_V5E_PCIE, GH200, H100_PCIE,
                              A100_PCIE)}
+
+# Chip spec by ``jax.devices()[0].device_kind`` (what JAX reports for the
+# attached chip). A kind that is not here is an error, never a default.
+DEVICE_KINDS = {"TPU v5 lite": TPU_V5E}
+
+
+def spec_for_device_kind(kind: str) -> HardwareSpec:
+    if kind not in DEVICE_KINDS:
+        raise KeyError(f"no HardwareSpec for device kind {kind!r}; "
+                       f"known: {sorted(DEVICE_KINDS)}")
+    return DEVICE_KINDS[kind]

@@ -8,7 +8,7 @@ the paper replays (scaled to a target request rate, preserving burst shape).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
@@ -331,9 +331,12 @@ def expert_skew_trace(specs: Sequence[ExpertSkewSpec], seed: int = 0):
 
 
 def tiny_trace(models: Sequence[str], n_per_model: int = 4,
-               prompt_len: int = 8, max_new: int = 6, vocab: int = 256,
-               spacing: float = 0.01, seed: int = 0) -> List[Request]:
-    """Small deterministic trace for functional engine tests."""
+               prompt_len: Union[int, Sequence[int]] = 8, max_new: int = 6,
+               vocab: int = 256, spacing: float = 0.01,
+               seed: int = 0) -> List[Request]:
+    """Small deterministic trace for functional engine tests. A sequence
+    of prompt lengths is cycled over the requests in arrival order."""
+    lens = [prompt_len] if isinstance(prompt_len, int) else list(prompt_len)
     rng = np.random.default_rng(seed)
     reqs = []
     t = 0.0
@@ -341,7 +344,8 @@ def tiny_trace(models: Sequence[str], n_per_model: int = 4,
         for m in models:
             reqs.append(Request(
                 rid=f"{m}-{i}", model=m,
-                prompt=rng.integers(0, vocab, prompt_len).astype(np.int32),
+                prompt=rng.integers(0, vocab, lens[len(reqs) % len(lens)])
+                .astype(np.int32),
                 max_new_tokens=max_new, arrival=t))
             t += spacing
     return reqs
