@@ -1,0 +1,217 @@
+"""CPU rehearsal of the chip benchmark: name resolution, seed-stable
+traffic, a well-formed result line from a tiny run through the test-only
+entry, the real entry's refusal without a TPU, and a cell added as files
+only."""
+from __future__ import annotations
+
+import copy
+import json
+import os
+import re
+import shutil
+import subprocess
+import sys
+import time
+
+import numpy as np
+import pytest
+
+CHIP = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(os.path.dirname(CHIP))
+sys.path.insert(0, CHIP)
+
+import gen  # noqa: E402
+import harness  # noqa: E402
+
+BIG_SEED = 2 ** 31 + 12345
+
+
+def bench():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def tiny(config, mix):
+    """Same code path, CPU-sized: 2 layers at small widths, short prompts."""
+    config, mix = copy.deepcopy(config), copy.deepcopy(mix)
+    for t in config["tenants"]:
+        t["arch"].update(hidden_size=64, intermediate_size=128,
+                         num_hidden_layers=2, num_attention_heads=4,
+                         num_key_value_heads=2, head_dim=16, vocab_size=256)
+        t["max_batch"], t["max_context"] = 4, 256
+    config["engine"].update(base_kv_pages=24, page_size=16,
+                            prefill_chunk_tokens=64)
+    for s in mix["streams"]:
+        key = "median" if "median" in s["prompt"] else "mean"
+        s["prompt"].update({key: 60, "min": 4, "max": 180})
+        s["output"].update(max=12)
+    mix["preroll"]["requests"] = 3
+    if config.get("setup_remap_alpha"):
+        # two layers leave room to donate one
+        config["setup_remap_alpha"] = {
+            k: 1 for k in config["setup_remap_alpha"]}
+        config["engine"]["runtime"]["max_remap_fraction"] = 0.5
+    return config, mix
+
+
+def test_every_cell_resolves_by_name():
+    b = bench()
+    assert b["paths"] == ["benchmarks/chip"]
+    for w in b["workloads"]:
+        info = harness.resolve(w["name"], b)
+        assert info["config"]["tenants"] and info["mix"]["streams"]
+        names = {m["name"] for m in info["per_layer"]}
+        assert names == {m["name"] for m in b["per_layer"]}
+        for m in info["per_layer"]:
+            assert callable(harness.load_reader(m["name"]))
+        e2e = {m["name"] for m in info["end_to_end"]}
+        assert {"setup_s", "tokens_per_s"} <= e2e
+        assert {m["moves"] for m in info["per_layer"]} <= e2e
+
+
+def test_configs_name_their_source_and_cuts():
+    for c in bench()["configs"]:
+        with open(os.path.join(ROOT, c["file"])) as f:
+            conf = json.load(f)
+        assert conf["source"] == c["source"]
+        assert sorted(conf["reduced"]) == sorted(c["reduced"])
+        assert conf["deployment"] and conf["limits"]["max_logit_gap"] > 0
+        for t in conf["tenants"]:
+            assert t["source"] and "assumed" in t
+
+
+@pytest.mark.parametrize("traffic", ["chat-fits", "chat-remap"])
+def test_traffic_is_seed_stable(traffic):
+    mix = harness.load_json(CHIP, "traffic", traffic + ".json")
+    vocab = {s["tenant"]: 32000 for s in mix["streams"]}
+    a = gen.generate(mix, BIG_SEED, 50, vocab)
+    b = gen.generate(mix, BIG_SEED, 50, vocab)
+    c = gen.generate(mix, 7, 50, vocab)
+    assert [(x.rid, x.due, x.max_new) for x in a] == \
+        [(x.rid, x.due, x.max_new) for x in b]
+    assert all(np.array_equal(x.prompt, y.prompt) for x, y in zip(a, b))
+    # another seed: the same work in another order
+    assert sorted(len(x.prompt) for x in a) == sorted(len(x.prompt) for x in c)
+    assert sorted(x.max_new for x in a) == sorted(x.max_new for x in c)
+    assert [len(x.prompt) for x in a] != [len(x.prompt) for x in c]
+    win = [x for x in a if not x.preroll]
+    assert win and all(0 <= x.due < 50 for x in win)
+    pre = [x for x in a if x.preroll]
+    assert len(pre) == mix["preroll"]["requests"]
+    assert all(x.due < 0 for x in pre)
+    assert gen.chunk_shapes(a, 256) == gen.chunk_shapes(c, 256)
+
+
+def tiny_run(workload, capsys):
+    rc = harness.run(["--workload", workload, "--seed",
+                      str(BIG_SEED), "--seconds", "6", "--trace", "0"],
+                     t_process=time.perf_counter(), check_device=False,
+                     scale=tiny)
+    assert rc == 0
+    out = capsys.readouterr()
+    res = json.loads(out.out.strip().splitlines()[-1])
+    assert set(res) >= {"correct", "attempted", "failed", "metrics",
+                        "device"}
+    assert list(res)[-1] == "checks"
+    assert res["correct"] is True
+    assert res["attempted"] > 0 and res["failed"] == 0
+    m = res["metrics"]
+    assert set(m) == {e["name"] for e in harness.resolve(
+        workload)["end_to_end"]}
+    assert all(v["value"] > 0 for v in m.values())
+    assert res["device"]["platform"] == "cpu"
+    assert out.err.strip().splitlines()[-1].startswith("check ")
+    calls = re.search(r"(\d+) decode calls \((\d+) remapped\)", out.err)
+    return int(calls[1]), int(calls[2])
+
+
+def test_tiny_run_prints_a_well_formed_last_line(capsys):
+    n, remapped = tiny_run("danube-4b.chat-fits", capsys)
+    assert n > 0 and remapped == 0
+
+
+def test_tiny_remap_run_decodes_on_donated_layers(capsys):
+    """Set-up donates the configured layers, and the window holds that
+    tier: every decode call streams layers from host memory."""
+    n, remapped = tiny_run("danube-4b-remap.chat-remap", capsys)
+    assert n > 0 and remapped == n
+
+
+def test_real_entry_refuses_without_a_tpu():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, os.path.join(CHIP, "run.py"), "--workload",
+         "danube-4b.chat-fits", "--seed", "1", "--seconds", "1",
+         "--trace", "0"], cwd=ROOT, env=env, capture_output=True,
+        text=True, timeout=120)
+    assert p.returncode != 0
+    assert "{" not in p.stdout
+
+
+def test_run_outside_the_repository_fails(tmp_path):
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    shutil.copytree(CHIP, tmp_path / "benchmarks" / "chip",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    p = subprocess.run(
+        [sys.executable, "benchmarks/chip/run.py", "--workload",
+         "danube-4b.chat-fits", "--seed", "1", "--seconds", "1",
+         "--trace", "0"], cwd=tmp_path, env=env, capture_output=True,
+        text=True, timeout=120)
+    assert p.returncode != 0
+    assert "{" not in p.stdout
+
+
+ADDED = r'''
+import json, os, sys
+sys.path.insert(0, os.path.join(sys.argv[1], "benchmarks", "chip"))
+import gen, harness
+info = harness.resolve("danube-4b.new-mix")
+vocab = {s["tenant"]: 32000 for s in info["mix"]["streams"]}
+items = gen.generate(info["mix"], 3, 10, vocab)
+reader = harness.load_reader("new_metric")
+print(json.dumps({"config": info["config"]["name"], "n": len(items),
+                  "metrics": [m["name"] for m in info["per_layer"]],
+                  "value": reader(None)}))
+'''
+
+
+def test_a_cell_config_and_metric_are_added_as_files_only(tmp_path):
+    """A new configuration, traffic mix and per-layer metric: new files and
+    new BENCHMARK.json entries, with no edit to any file already there."""
+    shutil.copytree(CHIP, tmp_path / "benchmarks" / "chip",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = {p: (tmp_path / p).read_bytes() for p in
+              [os.path.relpath(os.path.join(d, f), tmp_path)
+               for d, _, fs in os.walk(tmp_path) for f in fs]}
+    b = bench()
+    chip = tmp_path / "benchmarks" / "chip"
+    conf = json.loads((chip / "configs" / "danube-4b.json").read_text())
+    conf["name"] = "danube-4b-copy"
+    (chip / "configs" / "danube-4b-copy.json").write_text(json.dumps(conf))
+    mix = json.loads((chip / "traffic" / "chat-fits.json").read_text())
+    mix["name"], mix["rate_rps"] = "new-mix", 0.5
+    (chip / "traffic" / "new-mix.json").write_text(json.dumps(mix))
+    (chip / "metrics" / "new_metric.py").write_text(
+        "def read(r):\n    return 42.0\n")
+    b["configs"].append(dict(b["configs"][0], name="danube-4b-copy",
+                             file="benchmarks/chip/configs/"
+                                  "danube-4b-copy.json"))
+    b["workloads"].append({"name": "danube-4b.new-mix",
+                           "config": "danube-4b-copy", "traffic": "new-mix",
+                           "chips": 1, "why": "added as files only"})
+    b["per_layer"].append({"name": "new_metric", "unit": "%",
+                           "better": "higher", "source": "program_counter",
+                           "layer": "model step", "moves": "tbt_p95_s",
+                           "workloads": ["danube-4b.new-mix"]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(b))
+    for p, data in before.items():
+        assert (tmp_path / p).read_bytes() == data, p
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run([sys.executable, "-c", ADDED, str(tmp_path)],
+                       env=env, capture_output=True, text=True, timeout=120)
+    assert p.returncode == 0, p.stderr
+    res = json.loads(p.stdout.strip().splitlines()[-1])
+    assert res == {"config": "danube-4b-copy", "n": res["n"],
+                   "metrics": ["new_metric"], "value": 42.0}
+    assert res["n"] > 0
