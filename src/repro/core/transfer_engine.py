@@ -35,7 +35,7 @@ Two cooperating mechanisms:
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -44,12 +44,10 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.expert_remap import (
     EXPERT_PARAM_KEYS, ExpertPlan, identity_expert_plan, residency_states,
-    step_fetch_plan, unit_expert,
+    unit_expert,
 )
 from repro.core.layer_selection import RemapPlan
-from repro.core.transfer_pipeline import (
-    PlanDrain, StepTiming, identity_plan, simulate_decode_step,
-)
+from repro.core.transfer_pipeline import PlanDrain, identity_plan
 from repro.models.common import is_spec
 
 
@@ -171,8 +169,12 @@ def make_fetch(resident, cycle: PackedLayers,
             resident)
 
     def from_cycle(i):
-        words = jax.lax.dynamic_index_in_dim(cycle.buf, i, keepdims=False)
-        return cycle.layer(jax.device_put(words, jax.memory.Space.Device))
+        # the scope names the host->HBM copy and its unpacking in the
+        # compiled program's op metadata (and so in the device trace)
+        with jax.named_scope("remap_fetch"):
+            words = jax.lax.dynamic_index_in_dim(cycle.buf, i,
+                                                 keepdims=False)
+            return cycle.layer(jax.device_put(words, jax.memory.Space.Device))
 
     if len(cycle) == 0:                 # degenerate: single-stack fetch
         return lambda r: from_resident(idx[r])
@@ -196,8 +198,6 @@ class TransferStats:
     stream_bytes: int = 0               # per-token cycling transfers
     tier_switches: int = 0
     drain_bytes: int = 0                # host->device bytes moved by advance()
-    bubble_time_s: float = 0.0          # modeled pipeline stall (event model)
-    decode_time_s: float = 0.0          # modeled decode time incl. stalls
 
 
 class TransferEngine:
@@ -221,7 +221,6 @@ class TransferEngine:
         self.pending: Dict[str, PlanDrain] = {}
         self.stats = TransferStats()
         self._target_alpha: Dict[str, int] = {}
-        self._cold: Dict[str, bool] = {}   # plan switched since last decode
         # expert-granular state (MoE tenants; unit = one expert's weights)
         self.expert_host: Dict[str, Any] = {}
         self.expert_unit_bytes: Dict[str, int] = {}
@@ -229,7 +228,6 @@ class TransferEngine:
         self.expert_plans: Dict[str, ExpertPlan] = {}
         self.expert_pending: Dict[str, PlanDrain] = {}
         self._expert_flat: Dict[str, RemapPlan] = {}
-        self._expert_cold: Dict[str, bool] = {}
 
     def register(self, name: str, blocks, layer_bytes: int) -> None:
         """Take a model's stacked blocks. Under the identity plan the
@@ -250,7 +248,6 @@ class TransferEngine:
         self.split.pop(name, None)
         self.split[name] = split_blocks(
             self.host_copy[name] if blocks is None else blocks, plan)
-        self._cold[name] = True
 
     def submit_plan(self, name: str, plan: RemapPlan) -> None:
         """Begin an async tier switch. Drops (resident->cycle) happen now;
@@ -308,23 +305,13 @@ class TransferEngine:
     def fetch_for(self, name: str):
         return make_fetch(*self.split[name])
 
-    def note_decode_step(self, name: str, t_compute_layer: float = None,
-                         t_fetch_layer: float = None) -> Optional[StepTiming]:
-        """Account the per-token streaming traffic of the active plan.
-        With per-layer compute/fetch times, additionally resolve the step
-        through the shared event pipeline and accumulate the modeled
-        bubble — the same accounting the simulator charges, so both
-        runtimes agree on bubbles for the same plan."""
-        plan = self.plans[name]
-        self.stats.stream_bytes += plan.m * self.layer_bytes[name]
-        if t_compute_layer is None or t_fetch_layer is None or not plan.m:
-            return None
-        timing = simulate_decode_step(
-            plan, t_compute_layer, t_fetch_layer,
-            cold=self._cold.pop(name, False))
-        self.stats.bubble_time_s += timing.bubble_time
-        self.stats.decode_time_s += timing.total
-        return timing
+    def note_stream(self, name: str) -> int:
+        """Count one decode step's streaming: each of the plan's m cycle
+        layers crosses host->HBM as one packed buffer. Returns the bytes
+        (from shapes; nothing is read from the device)."""
+        n = self.split[name][1].buf.nbytes
+        self.stats.stream_bytes += n
+        return n
 
     # ------------------------------------------------------------------
     # expert-granular remapping (MoE tenants)
@@ -332,8 +319,7 @@ class TransferEngine:
     # The same PlanDrain state machine and byte counters, at the unit
     # ``unit = moe_layer * num_experts + expert`` (one expert's 3*d*d_expert
     # SwiGLU weights). Residency plans have m == alpha (a donated expert
-    # only streams on the steps it is routed to); the β double-buffered
-    # slots enter per decode step via ``step_fetch_plan``.
+    # only streams on the steps it is routed to).
 
     def register_experts(self, name: str, moe_blocks, expert_bytes: int,
                          num_moe_layers: int, num_experts: int) -> None:
@@ -346,7 +332,6 @@ class TransferEngine:
         plan = identity_expert_plan(num_moe_layers, num_experts)
         self.expert_plans[name] = plan
         self._expert_flat[name] = plan.to_remap_plan()
-        self._expert_cold[name] = True
 
     def submit_expert_plan(self, name: str, plan: ExpertPlan) -> None:
         """Begin an async expert-residency switch. Donations (resident ->
@@ -374,7 +359,6 @@ class TransferEngine:
             self.expert_pending[name] = drain
         if drain.current_plan != self._expert_flat[name]:
             self._expert_flat[name] = drain.current_plan
-            self._expert_cold[name] = True
         self.expert_plans[name] = plan
 
     def advance_experts(self, name: str, budget_bytes) -> int:
@@ -387,7 +371,6 @@ class TransferEngine:
         if drain.done:
             del self.expert_pending[name]
             self._expert_flat[name] = drain.target
-            self._expert_cold[name] = True
         return used
 
     def expert_residency(self, name: str) -> Dict[str, set]:
@@ -429,23 +412,6 @@ class TransferEngine:
                 return tuple(out) if isinstance(t, tuple) else out
             return t
         return walk(tree)
-
-    def note_moe_decode_step(self, name: str, t_compute_slot: float,
-                             t_fetch_expert: float, cold_counts,
-                             top_k: int, beta: int = 2) -> StepTiming:
-        """Account one decode step's cold-expert fetches: build the routed
-        -slot fetch schedule and resolve it through the shared event
-        pipeline — the same model ``PerfModel.expert_decode_timing``
-        charges, so engine and simulator agree by construction."""
-        L, _E = self.expert_dims[name]
-        plan = step_fetch_plan(L, top_k, cold_counts, beta=beta)
-        self.stats.stream_bytes += plan.m * self.expert_unit_bytes[name]
-        timing = simulate_decode_step(
-            plan, t_compute_slot, t_fetch_expert,
-            cold=self._expert_cold.pop(name, False))
-        self.stats.bubble_time_s += timing.bubble_time
-        self.stats.decode_time_s += timing.total
-        return timing
 
 
 def _repeats(blocks) -> int:

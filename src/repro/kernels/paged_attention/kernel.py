@@ -221,6 +221,7 @@ def paged_prefill_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, sq, group, d), q.dtype),
         interpret=interpret,
+        name="paged_prefill_attention",
     )(page_table.astype(jnp.int32), context_lens.astype(jnp.int32),
       q_start.astype(jnp.int32), qg, kp, vp)
     return jnp.moveaxis(out, 1, 2).reshape(b, sq, hq, d)
@@ -279,5 +280,6 @@ def paged_decode_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(page_table.astype(jnp.int32), context_lens.astype(jnp.int32), qg, kp, vp)
     return out.reshape(b, hq, d)

@@ -13,9 +13,15 @@ modes, matching the paper's comparison:
   * ``swap``   — Pie-style: pool extends into host memory (functionally a
     growth; the bidirectional-transfer cost is charged by the simulator).
 
-Timing is *not* measured here (CPU wall-time is meaningless for GH200/TPU
-claims): the engine records per-token *step indices* and event counts; the
-event-driven simulator (serving/simulator.py) owns latency/throughput.
+The step index is the engine's clock: arrivals, token times and SLO
+targets count steps. Beside it the engine records host-clock spans
+(``serving/tracing.py``): ``engine.step`` and, inside it, admission,
+prefill chunks, decode (with its page-table round trip and its wait on the
+device), memory control and tier switches; per-request ``queued`` and
+``prefill`` spans, which split a request's time to first token into its
+wait for a slot and its prefill, by rid; and one instant per ``events``
+entry, which puts that log on the trace's timeline. Under
+``jax.profiler`` all of them land in the trace beside the device's ops.
 Output-equivalence of mirage vs vllm modes is what the integration tests
 assert — remapping must never change results.
 """
@@ -38,6 +44,7 @@ from repro.core import (
 )
 from repro.models import build_model
 from repro.models.common import tree_bytes
+from repro.serving import tracing
 from repro.serving.hw import HardwareSpec, TPU_V5E
 from repro.serving.perf_model import PerfModel, kv_bytes_per_token
 from repro.serving.request import (
@@ -53,14 +60,14 @@ def make_decode(model, paged: bool, max_context: int):
     """One decode step over a tenant's ``(resident, cycle, maps)`` split:
     every layer comes through ``make_fetch``, so cycle layers cross from
     ``pinned_host`` into HBM inside the step. Params hold no blocks."""
-    def step(params, split, state, tokens):
+    def step_decode(params, split, state, tokens):
         fetch = make_fetch(*split)
         if paged:
             return model.impl.decode_step_paged(
                 params, state, tokens, fetch=fetch)
         return model.decode_step(params, state, tokens, max_context,
                                  fetch=fetch)
-    return step
+    return step_decode       # the device program is named jit_step_decode
 
 
 def execute_remap_decision(allocator, store, elastic_pages, d, *,
@@ -324,6 +331,8 @@ class ServingEngine:
         """Enqueue arrivals (append-safe incremental ``merge_arrivals``:
         the cluster router feeds requests as their steps come due)."""
         from repro.serving.runtime import merge_arrivals
+        for r in reqs:
+            tracing.begin("request.queued", r.rid, tenant=r.model)
         self._incoming = merge_arrivals(self._incoming, reqs)
 
     def busy(self) -> bool:
@@ -473,8 +482,7 @@ class ServingEngine:
         assert new_pages == list(pages), (new_pages, pages)
         self.allocator.cache_hold(new_pages)
         self.allocator.free(rid)
-        self.events.append((self.step_idx, "prefix-import",
-                            f"{model} blocks={len(new_pages)}"))
+        self._event("prefix-import", f"{model} blocks={len(new_pages)}")
         return len(new_pages) * ps
 
     def prefix_snapshot(self, max_blocks: int = 0):
@@ -528,8 +536,7 @@ class ServingEngine:
                 progressed = True
             if progressed:
                 self.xfer.submit_plan(name, identity_plan(info.num_layers))
-                self.events.append(
-                    (self.step_idx, "revert-teardown", name))
+                self._event("revert-teardown", name)
 
     def run(self, max_steps: int = 10_000) -> List[Request]:
         while self.step_idx < max_steps and self.busy():
@@ -553,7 +560,13 @@ class ServingEngine:
         # so the gap is walked normally until the drain completes.
         self.step_idx += self._idle_jump()
         self.step_idx += 1
-        now = float(self.step_idx)
+        with tracing.span("engine.step", step=self.step_idx) as sp:
+            self._step(float(self.step_idx))
+            # the allocator gauge, read on the host after the step
+            sp.set(kv_used=self.allocator.used_pages,
+                   kv_total=self.allocator.total_pages)
+
+    def _step(self, now: float) -> None:
         # 1. admit arrivals (functional time: step index)
         while self._incoming and self._incoming[0].arrival <= now:
             r = self._incoming.popleft()
@@ -590,7 +603,15 @@ class ServingEngine:
         # per step (the link carries about one layer per iteration), so a
         # decision's first decode step never serializes on the whole plan
         for n, info in self.store.models.items():
-            self.xfer.advance(n, info.layer_bytes)
+            if n in self.xfer.pending:
+                with tracing.span("xfer.advance", tenant=n) as sp:
+                    sp.set(bytes=self.xfer.advance(n, info.layer_bytes))
+
+    def _event(self, kind: str, detail: str) -> None:
+        """One entry of the step-indexed ``events`` log, and its host-clock
+        instant."""
+        self.events.append((self.step_idx, kind, detail))
+        tracing.instant("event." + kind, step=self.step_idx, detail=detail)
 
     # ------------------------------------------------------------- internals
     def _slo_slack(self, now: float) -> Dict[str, float]:
@@ -642,18 +663,19 @@ class ServingEngine:
     def _memory_control(self, pressure: bool) -> None:
         if self.mode == "vllm":
             return  # recompute handled at allocation sites
-        if self.mode == "swap":
-            if pressure:
-                seg = self.allocator.grow(16, "host-swap")
-                for t in self.tenants.values():
-                    if t.paged:
-                        t.grow_pool(self.allocator.page_id_bound)
-                self.events.append((self.step_idx, "swap-grow", f"{seg.num_pages}"))
-            return
-        decisions = self.controller.step(
-            kv_pressure=pressure, t_compute=self._t_compute())
-        for d in decisions:
-            self._apply_decision(d)
+        with tracing.span("engine.memory_control", pressure=int(pressure)):
+            if self.mode == "swap":
+                if pressure:
+                    seg = self.allocator.grow(16, "host-swap")
+                    for t in self.tenants.values():
+                        if t.paged:
+                            t.grow_pool(self.allocator.page_id_bound)
+                    self._event("swap-grow", f"{seg.num_pages}")
+                return
+            decisions = self.controller.step(
+                kv_pressure=pressure, t_compute=self._t_compute())
+            for d in decisions:
+                self._apply_decision(d)
 
     def _drop_cached_in_segments(self, model: str) -> None:
         cand = [p for seg in self.allocator.segments
@@ -665,21 +687,35 @@ class ServingEngine:
                 self.allocator.cache_drop(dropped)
 
     def _apply_decision(self, d: RemapDecision) -> None:
-        outcome = execute_remap_decision(
-            self.allocator, self.store, self._elastic_pages, d,
-            drop_cached=self._drop_cached_in_segments if self.prefix else None)
-        if outcome not in ("remap", "revert"):
-            return
-        self.xfer.submit_plan(d.model, d.plan)
-        if outcome == "remap":
-            for t in self.tenants.values():     # donated memory becomes pages
-                if t.paged:
-                    t.grow_pool(self.allocator.page_id_bound)
-        self.events.append(
-            (self.step_idx, outcome, f"{d.model} a={d.new_alpha}"))
+        with tracing.span("engine.tier_switch", tenant=d.model,
+                          alpha=d.new_alpha,
+                          pages_before=self.allocator.total_pages) as sp:
+            outcome = execute_remap_decision(
+                self.allocator, self.store, self._elastic_pages, d,
+                drop_cached=self._drop_cached_in_segments
+                if self.prefix else None)
+            sp.set(outcome=str(outcome))
+            if outcome not in ("remap", "revert"):
+                return
+            self.xfer.submit_plan(d.model, d.plan)
+            if outcome == "remap":
+                for t in self.tenants.values():  # donated memory: pages
+                    if t.paged:
+                        t.grow_pool(self.allocator.page_id_bound)
+            sp.set(pages_after=self.allocator.total_pages)
+            self._event(outcome, f"{d.model} a={d.new_alpha}")
 
     # -------------------------------------------------------------- prefill
     def _admit(self, t: Tenant) -> bool:
+        if not t.queue:
+            return False
+        with tracing.span("engine.admit", tenant=t.name) as sp:
+            n = len(t.running())
+            pressure = self._admit_queue(t)
+            sp.set(admitted=len(t.running()) - n)
+        return pressure
+
+    def _admit_queue(self, t: Tenant) -> bool:
         pressure = False
         idx = self.prefix.get(t.name)
         while t.queue:
@@ -701,7 +737,8 @@ class ServingEngine:
                 t.queue.popleft()
                 r.finished = True
                 self.finished.append(r)
-                self.events.append((self.step_idx, "drop-unserveable", r.rid))
+                tracing.discard("request.queued", r.rid)
+                self._event("drop-unserveable", r.rid)
                 continue
             match = None
             if idx is not None:
@@ -775,11 +812,11 @@ class ServingEngine:
             pages = idx.evict(need_pages - freed, evictable=self._cache_only)
             if pages:
                 freed += self.allocator.cache_drop(pages)
-                self.events.append(
-                    (self.step_idx, "cache-evict", f"{name} n={len(pages)}"))
+                self._event("cache-evict", f"{name} n={len(pages)}")
         return freed
 
     def _prefill(self, t: Tenant, r: Request, slot: int) -> None:
+        self._slot_taken(r, slot)
         prompt = jnp.asarray(r.prompt[None, :])
         batch = {"tokens": prompt}
         if t.cfg.is_encoder_decoder:
@@ -805,7 +842,15 @@ class ServingEngine:
         r.generated.append(tok)
         r.t_first_token = float(self.step_idx)
         r.token_times.append(float(self.step_idx))
-        self.events.append((self.step_idx, "prefill", r.rid))
+        tracing.end("request.prefill", r.rid, tokens=r.prompt_len)
+        self._event("prefill", r.rid)
+
+    @staticmethod
+    def _slot_taken(r: Request, slot: int) -> None:
+        """A request leaves the queue for a batch slot: its wait ends and
+        its prefill begins."""
+        tracing.end("request.queued", r.rid, slot=slot)
+        tracing.begin("request.prefill", r.rid, tenant=r.model)
 
     def _prefill_paged(self, t: Tenant, r: Request, slot: int, batch):
         """Prefill and scatter the KV into this request's allocator pages.
@@ -857,6 +902,7 @@ class ServingEngine:
         slot's page-table row is installed, and the write cursor starts at
         the CoW-shared prefix (those pages already hold this prefix's KV).
         ``_prefill_step`` then advances the prompt in bounded chunks."""
+        self._slot_taken(r, slot)
         t.slots[slot] = r
         r.slot = slot
         r.prefilling = True
@@ -884,21 +930,26 @@ class ServingEngine:
                         r.prompt_len - r.prefill_pos)
             if chunk <= 0:
                 continue
-            tokens = jnp.asarray(
-                r.prompt[r.prefill_pos:r.prefill_pos + chunk])
-            logits, t.state = t.model.impl.prefill_chunk_paged(
-                t.params, t.state, r.slot, tokens, r.prefill_pos,
-                fetch=self.xfer.fetch_for(t.name))
-            r.prefill_pos += chunk
-            spent += chunk
-            if r.prefill_pos >= r.prompt_len:
+            with tracing.span("engine.prefill_chunk", tenant=t.name,
+                              rid=r.rid, start=r.prefill_pos, tokens=chunk):
+                tokens = jnp.asarray(
+                    r.prompt[r.prefill_pos:r.prefill_pos + chunk])
+                logits, t.state = t.model.impl.prefill_chunk_paged(
+                    t.params, t.state, r.slot, tokens, r.prefill_pos,
+                    fetch=self.xfer.fetch_for(t.name))
+                r.prefill_pos += chunk
+                spent += chunk
+                if r.prefill_pos < r.prompt_len:
+                    continue
                 r.prefilling = False
-                tok = int(jnp.argmax(logits))
+                with tracing.span("engine.prefill_wait"):
+                    tok = int(jnp.argmax(logits))
                 r.generated.append(tok)
                 r.t_first_token = float(self.step_idx)
                 r.token_times.append(float(self.step_idx))
+                tracing.end("request.prefill", r.rid, tokens=r.prompt_len)
                 self._publish(t, r, np.asarray(r.prompt))
-                self.events.append((self.step_idx, "prefill", r.rid))
+                self._event("prefill", r.rid)
         return spent
 
     def _publish(self, t: Tenant, r: Request, tokens: np.ndarray) -> None:
@@ -962,33 +1013,26 @@ class ServingEngine:
         tokens = np.zeros((t.max_batch,), np.int32)
         for r in reqs:
             tokens[r.slot] = r.generated[-1]
-        if t.paged:
-            # per-token page allocations land in the allocator; sync the
-            # running slots' page-table rows before the step
-            pt = np.asarray(t.state["page_table"]).copy()
-            for r in reqs:
-                pt[r.slot] = t.page_row(self.allocator.seq_pages[r.rid])
-            t.state = dict(t.state, page_table=jnp.asarray(pt))
         # the interim plan mid-drain keeps pending layers in the cycle set,
         # so the remapped fetch path stays consistent through a tier switch
-        plan = self.xfer.plans[t.name]
-        batch = len(reqs)
-        avg_ctx = sum(r.total_len for r in reqs) / batch
-        logits, t.state = t.decode(
-            t.params, self.xfer.split[t.name], t.state, jnp.asarray(tokens))
-        if plan.m > 0:
-            # shared-pipeline bubble accounting (same event model and
-            # inputs the simulator charges for this plan)
-            t_c_layer, t_f_layer = t.perf.pipeline_inputs(
-                batch, avg_ctx, plan)
-            self.xfer.note_decode_step(t.name, t_c_layer, t_f_layer)
-        else:
-            # non-remapped steps still count in the modeled decode time,
-            # so bubble_fraction = stall / TOTAL decode time matches the
-            # simulator's denominator
-            self.xfer.stats.decode_time_s += \
-                t.perf.decode_step_time(batch, avg_ctx)
-        choices = np.asarray(jnp.argmax(logits, axis=-1))
+        host_bytes = self.xfer.note_stream(t.name)
+        with tracing.span("engine.decode", tenant=t.name, batch=len(reqs),
+                          m=self.xfer.plans[t.name].m,
+                          host_bytes=host_bytes):
+            if t.paged:
+                # per-token page allocations land in the allocator; sync
+                # the running slots' page-table rows before the step
+                with tracing.span("engine.page_table_sync"):
+                    pt = np.asarray(t.state["page_table"]).copy()
+                    for r in reqs:
+                        pt[r.slot] = t.page_row(
+                            self.allocator.seq_pages[r.rid])
+                    t.state = dict(t.state, page_table=jnp.asarray(pt))
+            logits, t.state = t.decode(
+                t.params, self.xfer.split[t.name], t.state,
+                jnp.asarray(tokens))
+            with tracing.span("engine.decode_wait"):
+                choices = np.asarray(jnp.argmax(logits, axis=-1))
         for r in list(reqs):
             r.generated.append(int(choices[r.slot]))
             r.token_times.append(float(self.step_idx))
@@ -1031,7 +1075,9 @@ class ServingEngine:
         r.prefilling = False
         r.prefill_pos = 0
         t.queue.appendleft(r)
-        self.events.append((self.step_idx, "preempt", r.rid))
+        tracing.discard("request.prefill", r.rid)
+        tracing.begin("request.queued", r.rid, tenant=r.model)
+        self._event("preempt", r.rid)
 
     def _finish(self, t: Tenant, r: Request) -> None:
         # publish the conversation so the next turn's prompt (this prompt +
@@ -1048,19 +1094,12 @@ class ServingEngine:
         t.clear_slot(r.slot)
         r.finished = True
         self.finished.append(r)
-        self.events.append((self.step_idx, "finish", r.rid))
+        self._event("finish", r.rid)
 
     # ---------------------------------------------------------------- report
     def metrics(self) -> ServingMetrics:
         m = ServingMetrics.from_requests(
             self.finished, makespan=float(self.step_idx))
-        st = self.xfer.stats
-        # modeled SECONDS (PerfModel clock) while the engine's latency
-        # metrics count steps — cross-compare via bubble_fraction only
-        m.bubble_time = st.bubble_time_s
-        m.bubble_fraction = (st.bubble_time_s / st.decode_time_s
-                             if st.decode_time_s else 0.0)
-        m._decode_time = st.decode_time_s
         m.unfinished = self.inflight()
         return m
 

@@ -267,9 +267,7 @@ class PerfModel:
         ``l`` this step; each crosses the host link once, double-buffered
         through ``beta`` slots. The per-slot compute budget is the
         bandwidth-bound scalar time spread over the routed slots — the
-        expert analog of ``pipeline_inputs``, and the same derivation
-        ``TransferEngine.note_moe_decode_step`` charges, so engine and
-        simulator agree on bubbles by construction."""
+        expert analog of ``pipeline_inputs``."""
         plan = step_fetch_plan(n_moe_layers, top_k, cold_counts, beta=beta)
         t_slot = self._decode_scalar(batch, avg_ctx, resident_fraction, 0) \
             / max(plan.n, 1)

@@ -969,8 +969,7 @@ class Simulator:
         """One decode iteration for an expert-granular MoE tenant: feed the
         trace's routing profile into the smoothed stats, derive per-layer
         expected cold-expert fetches from the interim residency, and
-        resolve the step through the shared event pipeline (same charging
-        as ``TransferEngine.note_moe_decode_step``)."""
+        resolve the step through the shared event pipeline."""
         es = self._expert[t.name]
         routing = self.expert_routing.get(t.name)
         if routing is not None:

@@ -378,44 +378,6 @@ def test_expert_plan_flatten_roundtrip():
 
 
 # ===========================================================================
-# 2b. engine vs simulator timing agreement
-# ===========================================================================
-
-@pytest.mark.parametrize("batch,cold_pattern", [
-    (1, "none"), (8, "sparse"), (32, "dense")])
-def test_engine_sim_step_timing_agree(batch, cold_pattern):
-    """``TransferEngine.note_moe_decode_step`` and
-    ``PerfModel.expert_decode_timing`` resolve the identical routed-slot
-    schedule through the shared event pipeline — totals, bubbles and
-    misses must agree exactly, cold and warm."""
-    cfg = ARCHS["moonshot-v1-16b-a3b"]
-    pm = PerfModel(cfg, GH200)
-    L, K, E = cfg.num_moe_layers(), cfg.moe.top_k, cfg.moe.num_experts
-    cold_counts = {
-        "none": [0] * L,
-        "sparse": [1 if l % 8 == 0 else 0 for l in range(L)],
-        "dense": [min(2, K)] * L,
-    }[cold_pattern]
-    rf = 0.9
-    te = TransferEngine()
-    te.register_experts("m", _expert_tree(L, E, width=1),
-                        pm.expert_bytes, L, E)
-    t_slot = pm._decode_scalar(batch, 512, rf, 0) / (L * K)
-    for cold in (True, False):        # register leaves the engine cold once
-        sim_t = pm.expert_decode_timing(
-            batch, 512, n_moe_layers=L, top_k=K, cold_counts=cold_counts,
-            resident_fraction=rf, cold=cold)
-        eng_t = te.note_moe_decode_step(
-            "m", t_slot, pm.t_transfer_expert, cold_counts, K)
-        assert math.isclose(eng_t.total, sim_t.total, rel_tol=1e-12)
-        assert math.isclose(eng_t.bubble_time, sim_t.bubble_time,
-                            rel_tol=1e-12, abs_tol=1e-15)
-        assert eng_t.misses == sim_t.misses
-    streamed = sum(min(c, K) for c in cold_counts)
-    assert te.stats.stream_bytes == 2 * streamed * pm.expert_bytes
-
-
-# ===========================================================================
 # 3. config accessors
 # ===========================================================================
 

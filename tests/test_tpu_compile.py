@@ -133,9 +133,16 @@ def test_remapped_decode_step_compiles(on_chip, kernels_on):
              "page_table": on_chip((BATCH, CONTEXT // PAGE), jnp.int32),
              "ctx": on_chip((BATCH,), jnp.int32)}
     step = jax.jit(make_decode(model, paged=True, max_context=CONTEXT))
-    compiled = step.lower(params, split, state,
-                          on_chip((BATCH,), jnp.int32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    lowered = step.lower(params, split, state, on_chip((BATCH,), jnp.int32))
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    # the names the benchmark's trace readers select the programs by
+    assert "jit_step" in lowered.as_text().split("\n", 1)[0]
+    assert "paged_decode" in hlo
+    # the cycle layers' host->HBM copies carry the transfer engine's scope
+    assert any("remap_fetch" in line for line in hlo.splitlines()
+               if "metadata={" in line)
     # the cycle stack is read from host memory, not copied into HBM whole
     assert compiled.memory_analysis().host_argument_size_in_bytes \
         == _nbytes(split[1])

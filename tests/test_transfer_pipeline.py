@@ -243,31 +243,6 @@ def test_transfer_engine_resubmit_mid_drain():
     assert eng.plans["m"] == _uniform(n, 1, 3) and not eng.pending
 
 
-# --------------------------------------- engine/simulator bubble agreement
-def test_engine_and_simulator_agree_on_bubble_accounting():
-    """Both runtimes resolve the same plan through the same event model
-    with identically derived inputs: the engine's note_decode_step and
-    the simulator's decode_step_timing must charge the same bubble."""
-    pm = PerfModel(ARCHS["granite-3-8b"], GH200)
-    n = pm.repeats
-    plan = _uniform(n, 4, 6)
-    batch, ctx = 16, 1024.0
-    # simulator side
-    sim_timing = pm.decode_step_timing(batch, ctx, plan)
-    # engine side: the shared input derivation ServingEngine._decode
-    # feeds TransferEngine.note_decode_step
-    t_c_layer, t_f_layer = pm.pipeline_inputs(batch, ctx, plan)
-    eng = TransferEngine()
-    eng.register("m", _blocks(4), pm.unit_bytes)
-    eng.plans["m"] = plan                       # inject: timing-only check
-    eng._cold.pop("m", None)                    # warm, like the sim's steady
-    eng_timing = eng.note_decode_step("m", t_c_layer, t_f_layer)
-    assert eng_timing.bubble_time == pytest.approx(sim_timing.bubble_time)
-    assert eng_timing.total == pytest.approx(sim_timing.total)
-    assert eng.stats.bubble_time_s == pytest.approx(sim_timing.bubble_time)
-    assert eng.stats.decode_time_s == pytest.approx(sim_timing.total)
-
-
 def test_incremental_apply_first_step_cheaper_than_sync():
     """Acceptance: the first decode step after a tier switch no longer
     pays the full plan transfer — a reversion keeps the old (warm,
