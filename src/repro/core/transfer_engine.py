@@ -4,15 +4,15 @@ Two cooperating mechanisms:
 
 1. **Compiled in-step streaming** (the TPU-native pipeline): a model's
    stacked layer parameters are split into a *resident* stack (device HBM)
-   and a *cycle* stack held in ``pinned_host`` memory. The decode step's
-   layer scan fetches each repeat's parameters with ``make_fetch``: resident
-   layers dynamic-index the device stack; cycling layers dynamic-index the
-   host stack and ``jax.device_put`` the slice into device memory *inside*
-   the jitted step — XLA's latency-hiding scheduler overlaps these
-   host->HBM DMAs with the previous layers' compute, which is exactly the
-   paper's per-layer prefetch pipeline (the β buffer slots are the transfer
-   buffers XLA allocates; β is enforced by the feasibility check in
-   ``layer_selection``, not by hand-managed slots).
+   and a *cycle* stack held in ``pinned_host`` memory. The layer scans run
+   each repeat through ``make_fetch``: resident layers dynamic-index the
+   device stack; cycling layers dynamic-index the host stack and
+   ``jax.device_put`` the slice into device memory *inside* the jitted
+   step, and the layer runs inside the branch that fetched it. A copy is
+   issued inside its own layer's branch, so it cannot overlap the previous
+   layers' compute: the paper's per-layer prefetch pipeline (β buffer
+   slots; β is enforced by the feasibility check in ``layer_selection``)
+   is not built on the device.
 
 2. **Host-side tier switching**: increasing α *drops* device layers (the
    host copy is the only full copy, as in vLLM) and donates their bytes to
@@ -35,7 +35,7 @@ Two cooperating mechanisms:
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, List, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -48,7 +48,7 @@ from repro.core.expert_remap import (
 )
 from repro.core.layer_selection import RemapPlan
 from repro.core.transfer_pipeline import PlanDrain, identity_plan
-from repro.models.common import is_spec
+from repro.models.common import LayerFetch
 
 
 # ---------------------------------------------------------------------------
@@ -152,23 +152,27 @@ def split_blocks(blocks, plan: RemapPlan):
 
 
 def make_fetch(resident, cycle: PackedLayers,
-               maps: Dict[str, jax.Array]) -> Callable[[jax.Array], Any]:
+               maps: Dict[str, jax.Array]) -> LayerFetch:
     """Build the per-repeat parameter fetch for the layer scans.
 
     Resident layers dynamic-index the device stack. A cycle layer's words
     are dynamic-indexed out of the ``pinned_host`` buffer and
     ``jax.device_put`` into device memory inside the traced step, on every
-    backend, then unpacked there.
+    backend, then unpacked there. A split with both kinds picks the source
+    per repeat with a ``lax.cond``; ``LayerFetch.scan`` runs the layer
+    inside it, so the resident branch reads its layer in place.
     """
     is_resident = maps["is_resident"]
     idx = maps["idx_in_stack"]
 
-    def from_resident(i):
+    def from_resident(r):
+        i = idx[r]
         return jax.tree.map(
             lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
             resident)
 
-    def from_cycle(i):
+    def from_cycle(r):
+        i = idx[r]
         # the scope names the host->HBM copy and its unpacking in the
         # compiled program's op metadata (and so in the device trace)
         with jax.named_scope("remap_fetch"):
@@ -177,14 +181,10 @@ def make_fetch(resident, cycle: PackedLayers,
             return cycle.layer(jax.device_put(words, jax.memory.Space.Device))
 
     if len(cycle) == 0:                 # degenerate: single-stack fetch
-        return lambda r: from_resident(idx[r])
+        return LayerFetch(from_resident)
     if _repeats(resident) == 0:
-        return lambda r: from_cycle(idx[r])
-
-    def fetch(r):
-        return jax.lax.cond(is_resident[r], from_resident, from_cycle, idx[r])
-
-    return fetch
+        return LayerFetch(from_cycle)
+    return LayerFetch(from_cycle, from_resident, pick=is_resident)
 
 
 # ---------------------------------------------------------------------------

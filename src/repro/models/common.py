@@ -89,3 +89,55 @@ def stack_specs(spec_tree, n: int, stack_logical: str = "stack"):
 
 def dtype_of(cfg) -> jnp.dtype:
     return jnp.dtype(cfg.dtype)
+
+
+class LayerFetch:
+    """Where a layer scan finds repeat ``r``'s params: ``fetch(r)`` returns
+    them, and ``fetch.scan`` runs a layer function over every repeat.
+
+    ``sources`` are one or two functions of ``r``; with two, ``pick[r]``
+    (bool) chooses the second through a ``lax.cond``. Weights returned out
+    of that cond share one layout, and the compiler copies a whole device
+    stack into it to slice one layer out; so ``scan`` runs the whole layer
+    inside each branch instead."""
+
+    def __init__(self, *sources, pick=None):
+        assert len(sources) == (1 if pick is None else 2)
+        self.sources = sources
+        self.pick = pick
+
+    @classmethod
+    def stacked(cls, blocks) -> "LayerFetch":
+        """Dynamic index into stacked ``blocks`` (leaves [R, ...])."""
+        return cls(lambda r: jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, r, keepdims=False),
+            blocks))
+
+    def __call__(self, r):
+        if self.pick is None:
+            return self.sources[0](r)
+        return jax.lax.cond(self.pick[r], self.sources[1], self.sources[0], r)
+
+    def scan(self, layer_fn, carry, xs):
+        """``lax.scan`` of ``layer_fn(params_r, carry, *xs_r) -> (carry,
+        ys_r)`` over the repeats, ``xs`` a tuple of [R, ...] arrays. Under
+        the cond each branch slices ``xs`` itself: slices passed into the
+        cond would be copied once for each branch."""
+        n = xs[0].shape[0]
+        if self.pick is None:
+            def body(c, xs_r):
+                *xs_r, r = xs_r
+                return layer_fn(self.sources[0](r), c, *xs_r)
+            return jax.lax.scan(body, carry, (*xs, jnp.arange(n)))
+
+        def branch(source):
+            def run(r, c, xs):
+                return layer_fn(source(r), c, *(
+                    jax.lax.dynamic_index_in_dim(a, r, keepdims=False)
+                    for a in xs))
+            return run
+
+        def body(c, r):
+            return jax.lax.cond(self.pick[r], branch(self.sources[1]),
+                                branch(self.sources[0]), r, c, xs)
+        return jax.lax.scan(body, carry, jnp.arange(n))

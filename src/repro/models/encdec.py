@@ -16,7 +16,9 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import with_sharding_constraint
 from repro.models.blocks import Attention, SwiGLU, rms_norm, _einsum
-from repro.models.common import Spec, dtype_of, stack_specs, tree_init, is_spec
+from repro.models.common import (
+    LayerFetch, Spec, dtype_of, is_spec, stack_specs, tree_init,
+)
 
 _SELF = Attention()
 _CROSS = Attention(cross=True)
@@ -218,10 +220,7 @@ class EncDec:
         }
 
         if fetch is None:
-            def fetch(r):
-                return jax.tree.map(
-                    lambda a: jax.lax.dynamic_index_in_dim(a, r, keepdims=False),
-                    params["blocks"])
+            fetch = LayerFetch.stacked(params["blocks"])
 
         def body(x, xs):
             state_r, r = xs

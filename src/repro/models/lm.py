@@ -29,7 +29,9 @@ from repro.distributed.sharding import with_sharding_constraint
 from repro.models.blocks import (
     Attention, SwiGLU, MoE, Mamba, MLSTM, SLSTM, rms_norm, _einsum,
 )
-from repro.models.common import Spec, dtype_of, stack_specs, tree_init, is_spec
+from repro.models.common import (
+    LayerFetch, Spec, dtype_of, is_spec, stack_specs, tree_init,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -310,10 +312,7 @@ class LM:
             shared = {**shared, **extra_shared}
 
         if fetch is None:
-            def fetch(r):
-                return jax.tree.map(
-                    lambda a: jax.lax.dynamic_index_in_dim(a, r, keepdims=False),
-                    params["blocks"])
+            fetch = LayerFetch.stacked(params["blocks"])
 
         def body(x, xs):
             state_r, r = xs
@@ -352,6 +351,8 @@ class LM:
         donates parameter segments), page_table [B, N] int32, ctx [B] int32
         (tokens already in each sequence's cache). Pure-attention stacks
         only (SWA/SSM tenants use the dense ring/recurrent state path).
+        ``fetch`` (a ``LayerFetch``, default ``params['blocks']``) scans the
+        layers.
         """
         cfg = self.cfg
         assert all(ld.mixer == "attn" for ld in self.pattern), \
@@ -366,14 +367,10 @@ class LM:
         off = pos % page
 
         if fetch is None:
-            def fetch(r):
-                return jax.tree.map(
-                    lambda a: jax.lax.dynamic_index_in_dim(a, r, keepdims=False),
-                    params["blocks"])
+            fetch = LayerFetch.stacked(params["blocks"])
 
-        def body(x, xs):
-            pool_k, pool_v, r = xs
-            (p,) = fetch(r)
+        def layer(layer_params, x, pool_k, pool_v):
+            (p,) = layer_params
             attn = MIXERS["attn"]
             h = rms_norm(x, p["norm1"], cfg.norm_eps)
             q, k_new, v_new = attn._qkv(p["mixer"], h, cfg)
@@ -393,9 +390,7 @@ class LM:
                 x = x + h2
             return x, (pool_k, pool_v)
 
-        x, (pk, pv) = jax.lax.scan(
-            body, x,
-            (state["pool_k"], state["pool_v"], jnp.arange(self.repeats)))
+        x, (pk, pv) = fetch.scan(layer, x, (state["pool_k"], state["pool_v"]))
         logits = self.logits_last(params, x)
         new_state = dict(state, pool_k=pk, pool_v=pv, ctx=pos + 1)
         return logits, new_state
@@ -432,14 +427,10 @@ class LM:
         ctx_end = jnp.full((1,), start + s, jnp.int32)
 
         if fetch is None:
-            def fetch(r):
-                return jax.tree.map(
-                    lambda a: jax.lax.dynamic_index_in_dim(a, r, keepdims=False),
-                    params["blocks"])
+            fetch = LayerFetch.stacked(params["blocks"])
 
-        def body(x, xs):
-            pool_k, pool_v, r = xs
-            (p,) = fetch(r)
+        def layer(layer_params, x, pool_k, pool_v):
+            (p,) = layer_params
             attn = MIXERS["attn"]
             h = rms_norm(x, p["norm1"], cfg.norm_eps)
             q, k_new, v_new = attn._qkv(p["mixer"], h, cfg)      # [1,S,H,hd]
@@ -460,9 +451,7 @@ class LM:
                 x = x + h2
             return x, (pool_k, pool_v)
 
-        x, (pk, pv) = jax.lax.scan(
-            body, x,
-            (state["pool_k"], state["pool_v"], jnp.arange(self.repeats)))
+        x, (pk, pv) = fetch.scan(layer, x, (state["pool_k"], state["pool_v"]))
         logits = self.logits_last(params, x[:, -1])
         new_state = dict(
             state, pool_k=pk, pool_v=pv,

@@ -6,7 +6,9 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import ARCHS, scaled_config
-from repro.core import make_fetch, make_plan, split_blocks
+from repro.core import (
+    RemapPlan, identity_plan, make_fetch, make_plan, split_blocks,
+)
 from repro.models import build_model
 
 PAGE, NPAGES = 4, 24
@@ -83,3 +85,24 @@ def test_paged_pool_growth_preserves_content(setup):
         lg, st = m.impl.decode_step_paged(params, st, jnp.asarray([out[-1]]))
         out.append(int(jnp.argmax(lg[0])))
     assert out == dense
+
+
+@pytest.mark.parametrize("kind", ["identity", "mixed", "all-cycle"])
+def test_prefill_chunk_with_remap_fetch(setup, kind):
+    """A prefill chunk through a ``make_fetch`` split leaves the logits and
+    pools of the chunk run on the whole stack, whichever way the plan
+    splits the layers."""
+    cfg, m, params, prompt = setup
+    n = m.repeats
+    plan = {"identity": identity_plan(n),
+            "mixed": make_plan(n, alpha=1, t_c=1.0, t_t=0.5),
+            "all-cycle": RemapPlan(n, n, n, tuple(range(n)), ())}[kind]
+    fetch = make_fetch(*split_blocks(params["blocks"], plan))
+    st = _paged_state(m, params, prompt)
+    chunk = jax.random.randint(jax.random.PRNGKey(2), (5,), 0, cfg.vocab_size)
+    want, st_want = m.impl.prefill_chunk_paged(params, st, 0, chunk, 10)
+    got, st_got = m.impl.prefill_chunk_paged(params, st, 0, chunk, 10,
+                                             fetch=fetch)
+    for key, a in [("logits", want)] + sorted(st_want.items()):
+        b = got if key == "logits" else st_got[key]
+        assert jnp.array_equal(a, b), key

@@ -10,6 +10,7 @@ file.
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -105,9 +106,10 @@ def test_paged_prefill_kernel_compiles(on_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _remapped_tenant(sds, layers=4, cycling=2):
+def _remapped_tenant(sds, layers=5, cycling=2):
     """Danube-width model cut to ``layers``, ``cycling`` of them held in
-    pinned host memory: (model, params without blocks, split)."""
+    pinned host memory: (model, params without blocks, split). By default
+    the resident stack's 3 layers share no other dimension of the step."""
     cfg = dataclasses.replace(DANUBE, num_layers=layers)
     model = build_model(cfg)
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
@@ -126,17 +128,37 @@ def _remapped_tenant(sds, layers=4, cycling=2):
     return model, params, split
 
 
+def _paged_state(sds, model):
+    kp, vp = _pools(sds, layers=model.repeats)
+    return {"pool_k": kp, "pool_v": vp,
+            "page_table": sds((BATCH, CONTEXT // PAGE), jnp.int32),
+            "ctx": sds((BATCH,), jnp.int32)}
+
+
+def _stack_copies(hlo, resident):
+    """``copy``/``copy-start`` ops in ``hlo`` whose result has the shape of
+    a leaf of the ``resident`` stack: a re-layout of the whole stack."""
+    stack = {tuple(a.shape) for a in jax.tree.leaves(resident)}
+    found = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) copy(?:-start)?\(", line)
+        if m and any(tuple(int(d) for d in dims.split(",")) in stack
+                     for dims in re.findall(r"\[([\d,]+)\]", m.group(1))):
+            found.append(line.strip()[:120])
+    return found
+
+
 def test_remapped_decode_step_compiles(on_chip, kernels_on):
     model, params, split = _remapped_tenant(on_chip)
-    kp, vp = _pools(on_chip, layers=model.repeats)
-    state = {"pool_k": kp, "pool_v": vp,
-             "page_table": on_chip((BATCH, CONTEXT // PAGE), jnp.int32),
-             "ctx": on_chip((BATCH,), jnp.int32)}
+    state = _paged_state(on_chip, model)
     step = jax.jit(make_decode(model, paged=True, max_context=CONTEXT))
     lowered = step.lower(params, split, state, on_chip((BATCH,), jnp.int32))
     compiled = lowered.compile()
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo
+    # each branch of the fetch's cond runs the layer on the weights where
+    # they lie: no copy of the resident stack into another layout
+    assert _stack_copies(hlo, split[0]) == []
     # the names the benchmark's trace readers select the programs by
     assert "jit_step" in lowered.as_text().split("\n", 1)[0]
     assert "paged_decode" in hlo
@@ -148,12 +170,19 @@ def test_remapped_decode_step_compiles(on_chip, kernels_on):
         == _nbytes(split[1])
 
 
+def test_identity_plan_decode_step_has_no_cond(on_chip, kernels_on):
+    """Every layer resident: the fetch indexes one stack, with no branch."""
+    model, params, split = _remapped_tenant(on_chip, cycling=0)
+    step = jax.jit(make_decode(model, paged=True, max_context=CONTEXT))
+    hlo = step.lower(params, split, _paged_state(on_chip, model),
+                     on_chip((BATCH,), jnp.int32)).compile().as_text()
+    assert "paged_decode" in hlo
+    assert " conditional(" not in hlo
+
+
 def test_remapped_prefill_chunk_compiles(on_chip, kernels_on):
     model, params, split = _remapped_tenant(on_chip)
-    kp, vp = _pools(on_chip, layers=model.repeats)
-    state = {"pool_k": kp, "pool_v": vp,
-             "page_table": on_chip((BATCH, CONTEXT // PAGE), jnp.int32),
-             "ctx": on_chip((BATCH,), jnp.int32)}
+    state = _paged_state(on_chip, model)
 
     def chunk(params, split, state, slot, tokens, start):
         return model.impl.prefill_chunk_paged(
@@ -161,6 +190,8 @@ def test_remapped_prefill_chunk_compiles(on_chip, kernels_on):
     compiled = jax.jit(chunk).lower(
         params, split, state, on_chip((), jnp.int32),
         on_chip((CHUNK,), jnp.int32), on_chip((), jnp.int32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    assert _stack_copies(hlo, split[0]) == []
     assert compiled.memory_analysis().host_argument_size_in_bytes \
         == _nbytes(split[1])
