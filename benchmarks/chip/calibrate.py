@@ -7,8 +7,9 @@ benchmark's own runs.
 
 For each seed, in one process: a normal run of the cell (its result line
 gives the program's widest gap, ``max_logit_gap``), and, on the same
-sampled requests, the control: the plain reference computed in float8 e4m3
-(``reference.logits(..., fp8=True)``). The control's reading is
+sampled requests, the control: the plain reference one precision step
+lower (float8 e4m3 for the dense family's bfloat16), through the tenant's
+model family's ``control_gaps``. The control's reading is
 the widest gap, under the float32 reference, of the token the control puts
 first at each position of the same prompts and served tokens; it goes
 through the same decision as the run's own reading (``harness.judge``,
@@ -28,8 +29,6 @@ import sys  # noqa: E402
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import harness  # noqa: E402
-import reference  # noqa: E402
-import weights  # noqa: E402
 
 
 def control_reading(config, seed, chosen) -> dict:
@@ -38,10 +37,10 @@ def control_reading(config, seed, chosen) -> dict:
         mine = [tr for tr in chosen if tr.item.tenant == t["name"]]
         if not mine:
             continue
-        w = weights.make(t["arch"], seed, i)
+        fam = harness.tenant_family(t)
+        w = fam.make(t["arch"], seed, i)
         for tr in mine:
-            g = reference.control_gaps(w, t["arch"], tr.item.prompt,
-                                       tr.served())
+            g = fam.control_gaps(w, t["arch"], tr.item.prompt, tr.served())
             worst = max(worst, float(g.max()))
             n += len(g)
             misses += int((g > 0).sum())

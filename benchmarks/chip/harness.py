@@ -1,16 +1,45 @@
 """One benchmark run of one cell: set-up, an open-loop window, metrics and
 the comparison with the plain reference.
 
-Everything that belongs to one configuration, traffic mix or per-layer
-metric lives in its own file, found by the name that ``BENCHMARK.json``
-gives: ``configs/<config>.json``, ``traffic/<traffic>.json`` and
+Everything that belongs to one configuration, traffic mix, model family or
+per-layer metric lives in its own file, found by the name that
+``BENCHMARK.json`` (or a configuration) gives: ``configs/<config>.json``,
+``traffic/<traffic>.json``, ``families/<family>.py`` and
 ``metrics/<metric>.py``. This module is the same for every cell.
+
+A model family is the code that one architecture needs outside the
+program. A tenant of a configuration names it (``"family": "<name>"``;
+``dense`` where the key is absent), and ``resolve`` refuses a cell, before
+any set-up, whose family file is missing or lacks one of ``FAMILY_API``:
+
+* ``make(arch, seed, tenant_index)``: the benchmark's own weights, made on
+  the device from the seed, in the family's own layout;
+* ``to_program(w)``: the same arrays in the program's parameter tree;
+* ``program_config(base, arch)``: the program's ``ModelConfig`` for the
+  published keys ``arch``, starting from ``base``
+  (``repro.configs.get_arch(tenant["name"])``);
+* ``served_gaps(w, arch, prompt, served)``: per served token, the plain
+  float32 reference's best logit less the served token's logit;
+* ``control_gaps(w, arch, prompt, served)``: the same gap for the token
+  that the reference computed one precision step lower puts first;
+* ``decode_step_flops(arch, ctx)``: the FLOPs of one whole decode step
+  for live rows holding ``ctx`` tokens, from shapes.
+
+Only ``to_program`` and ``program_config`` may speak of the program; the
+weights and the reference import nothing of it. ``arch`` is the tenant's
+published keys as its configuration file holds them. A per-layer reader
+finds each tenant's family in ``Readings.families``.
+
+What the harness reads of the program's state: a paged tenant's state
+keeps its KV pool under ``pool_k``, pages on axis 1 (``shape_key``). A
+family whose program keeps one latent pool (multi-head latent attention)
+keeps it under that name.
 
 A run:
 
 1. set-up (``setup_s``, from process start to the window's opening):
-   weights made on the device from the seed (``weights.py``), the engine
-   built with them, the remap tier the configuration names (if any)
+   weights made on the device from the seed (the family's ``make``), the
+   engine built with them, the remap tier the configuration names (if any)
    reached, every prefill chunk (length and start) of this seed's traffic
    and the decode step warmed, then a pre-roll of the cell's own traffic;
 2. the window: an open loop that, between engine steps, submits every
@@ -18,14 +47,16 @@ A run:
    is stamped with the host time at which the ``ServingEngine.step`` that
    produced it returned (the step syncs on its argmax);
 3. the comparison: a sample of finished requests, drawn from the seed,
-   is run through the plain reference (``reference.py``) once the engine
-   is freed, and the widest gap by which a served token's logit lies below
-   the reference's best is held against the configuration's limit.
+   is run through the plain reference (the family's ``served_gaps``) once
+   the engine is freed, and the widest gap by which a served token's logit
+   lies below the reference's best is held against the configuration's
+   limit.
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -45,19 +76,12 @@ sys.path.insert(0, os.path.join(ROOT, "src"))
 
 import gen  # noqa: E402  (benchmark modules, beside this file)
 import peaks  # noqa: E402
-import reference  # noqa: E402
 import tracered  # noqa: E402
-import weights  # noqa: E402
 
-# published config keys -> the program's ModelConfig fields
-ARCH_FIELDS = {
-    "hidden_size": "d_model", "intermediate_size": "d_ff",
-    "num_hidden_layers": "num_layers", "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads", "head_dim": "head_dim",
-    "vocab_size": "vocab_size", "rms_norm_eps": "norm_eps",
-    "rope_theta": "rope_theta", "sliding_window": "sliding_window",
-    "tie_word_embeddings": "tie_embeddings", "torch_dtype": "dtype",
-}
+FAMILIES = os.path.join(HERE, "families")
+DEFAULT_FAMILY = "dense"
+FAMILY_API = ("make", "to_program", "program_config", "served_gaps",
+              "control_gaps", "decode_step_flops")
 
 
 def parse_args(argv=None):
@@ -74,9 +98,11 @@ def load_json(*parts):
         return json.load(f)
 
 
-def resolve(workload: str, bench: Optional[dict] = None) -> dict:
-    """The cell, its configuration file, its traffic mix and its metrics,
-    found by name."""
+def resolve(workload: str, bench: Optional[dict] = None,
+            scale=None) -> dict:
+    """The cell, its configuration file, its traffic mix, each tenant's
+    model family and its metrics, found by name. ``scale`` shrinks the
+    configuration and mix for a rehearsal on the CPU (tests only)."""
     bench = bench or load_json(ROOT, "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
@@ -84,15 +110,47 @@ def resolve(workload: str, bench: Optional[dict] = None) -> dict:
                          f"known: {sorted(cells)}")
     cell = cells[workload]
     conf = {c["name"]: c for c in bench["configs"]}[cell["config"]]
+    config = load_json(ROOT, conf["file"])
+    mix = load_json(HERE, "traffic", cell["traffic"] + ".json")
+    if scale is not None:
+        config, mix = scale(config, mix)
     return {
         "cell": cell,
-        "config": load_json(ROOT, conf["file"]),
-        "mix": load_json(HERE, "traffic", cell["traffic"] + ".json"),
+        "config": config,
+        "mix": mix,
+        "families": {t["name"]: tenant_family(t)
+                     for t in config["tenants"]},
         "end_to_end": [m for m in bench["end_to_end"]
                        if workload in m.get("workloads", [workload])],
         "per_layer": [m for m in bench["per_layer"]
                       if workload in m.get("workloads", [workload])],
     }
+
+
+def tenant_family(tenant: dict):
+    """The model family that a configuration's tenant names."""
+    return load_family(tenant.get("family", DEFAULT_FAMILY))
+
+
+def load_family(name: str):
+    """``families/<name>.py``, loaded once a process; exits, naming the
+    file and what it lacks, where it is missing or incomplete."""
+    return _load_family(os.path.join(FAMILIES, name + ".py"))
+
+
+@functools.lru_cache(maxsize=None)
+def _load_family(path: str):
+    if not os.path.isfile(path):
+        raise SystemExit(f"{path}: no such model family file")
+    name = "family_" + os.path.basename(path)[:-len(".py")]
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    missing = [f for f in FAMILY_API if not callable(getattr(mod, f, None))]
+    if missing:
+        raise SystemExit(f"{path}: the model family lacks "
+                         f"{', '.join(missing)}")
+    return mod
 
 
 def load_reader(name: str):
@@ -212,12 +270,9 @@ def instrument(eng, rec: Recorder) -> None:
 
 # ------------------------------------------------------------------ engine
 def model_config(tenant: dict):
-    import dataclasses as dc
     from repro.configs import get_arch
-    arch = tenant["arch"]
-    fields = {ARCH_FIELDS[k]: v for k, v in arch.items() if k in ARCH_FIELDS}
-    fields["sliding_window"] = fields.get("sliding_window") or 0
-    return dc.replace(get_arch(tenant["name"]), **fields)
+    return tenant_family(tenant).program_config(get_arch(tenant["name"]),
+                                                tenant["arch"])
 
 
 def build_engine(config: dict, seed: int, hw):
@@ -230,8 +285,9 @@ def build_engine(config: dict, seed: int, hw):
 
     tenants = {}
     for i, t in enumerate(config["tenants"]):
+        fam = tenant_family(t)
         cfg = model_config(t)
-        params = weights.to_program(weights.make(t["arch"], seed, i))
+        params = fam.to_program(fam.make(t["arch"], seed, i))
         want = jax.eval_shape(build_model(cfg).init, jax.random.PRNGKey(0))
         got = jax.tree.map(lambda a: (a.shape, a.dtype), params)
         if jax.tree.map(lambda a: (a.shape, a.dtype), want) != got:
@@ -375,13 +431,15 @@ def end_to_end(rec: Recorder, t_open: float, t_close: float) -> dict:
 class Readings:
     """What a per-layer metric reader gets."""
 
-    def __init__(self, rec, e2e, trace, archs, page_size, peak):
+    def __init__(self, rec, e2e, trace, archs, page_size, peak,
+                 families=None):
         self.rec = rec
         self.e2e = e2e
         self.trace = trace          # tracered.reduce(...) or None
         self.archs = archs          # tenant -> published arch keys
         self.page_size = page_size
         self.peak = peak            # peaks.peaks(device_kind)
+        self.families = families or {}  # tenant -> model family module
 
 
 # ------------------------------------------------------------- correctness
@@ -412,10 +470,10 @@ def compare(config: dict, seed: int, chosen: List[Track]) -> dict:
         mine = [tr for tr in chosen if tr.item.tenant == t["name"]]
         if not mine:
             continue
-        w = weights.make(t["arch"], seed, i)
+        fam = tenant_family(t)
+        w = fam.make(t["arch"], seed, i)
         for tr in mine:
-            g = reference.served_gaps(w, t["arch"], tr.item.prompt,
-                                      tr.served())
+            g = fam.served_gaps(w, t["arch"], tr.item.prompt, tr.served())
             worst = max(worst, float(g.max()))
             n_tok += len(g)
             misses += int((g > 0).sum())
@@ -437,10 +495,8 @@ def run(argv=None, *, t_process: float, check_device: bool = True,
     """One run; prints the result line last on stdout. ``scale`` shrinks
     the configuration and mix for a rehearsal on the CPU (tests only)."""
     args = parse_args(argv)
-    info = resolve(args.workload)
+    info = resolve(args.workload, scale=scale)
     config, mix, cell = info["config"], info["mix"], info["cell"]
-    if scale is not None:
-        config, mix = scale(config, mix)
 
     import jax
     devs = jax.devices()
@@ -570,7 +626,8 @@ def run(argv=None, *, t_process: float, check_device: bool = True,
         events = tracered.load_xplane(trace_dir)
         trace = tracered.reduce(events)
         shutil.rmtree(trace_dir, ignore_errors=True)
-    readings = Readings(rec, e2e, trace, archs, page_size, peak)
+    readings = Readings(rec, e2e, trace, archs, page_size, peak,
+                        info["families"])
 
     if args.trace:
         metrics = {}

@@ -215,3 +215,110 @@ def test_a_cell_config_and_metric_are_added_as_files_only(tmp_path):
     assert res == {"config": "danube-4b-copy", "n": res["n"],
                    "metrics": ["new_metric"], "value": 42.0}
     assert res["n"] > 0
+
+
+# the dense family under a new name, with weights of its own (those of the
+# next seed), so that a run whose engine or comparison went round the
+# family would serve one set of weights and judge by another
+DENSE2 = '''
+
+def make(arch, seed, tenant_index):
+    return weights.make(arch, seed + 1, tenant_index)
+'''
+# and, for the second case, readings that only this family gives
+DENSE2_OFF = '''
+
+def served_gaps(w, arch, prompt, served):
+    return reference.served_gaps(w, arch, prompt, served) + 1.0
+
+
+def control_gaps(w, arch, prompt, served):
+    return 0.0 * reference.control_gaps(w, arch, prompt, served) + 7.0
+'''
+
+FAMILY_RUN = r'''
+import json, os, sys, time
+chip = os.path.join(sys.argv[1], "benchmarks", "chip")
+sys.path[:0] = [chip, os.path.join(chip, "tests")]
+import calibrate, harness
+from test_chip_rehearsal import BIG_SEED, tiny
+compare, control = harness.compare, {}
+
+def both(config, seed, chosen):
+    out = compare(config, seed, chosen)
+    control.update(calibrate.control_reading(config, seed, chosen))
+    return out
+
+harness.compare = both
+rc = harness.run(["--workload", "danube-4b.dense2-chat", "--seed",
+                  str(BIG_SEED), "--seconds", "6", "--trace", sys.argv[2]],
+                 t_process=time.perf_counter(), check_device=False,
+                 scale=tiny)
+print("control " + json.dumps(control), file=sys.stderr)
+sys.exit(rc)
+'''
+
+
+@pytest.mark.parametrize("off", [False, True], ids=["sound", "gaps_off"])
+def test_a_model_family_is_added_as_files_only(tmp_path, off):
+    """A new model family, a configuration that names it, a cell and a
+    metric: new files and new BENCHMARK.json entries, with no edit to any
+    file already there. The run builds the engine, compares and reads the
+    control through that family, and hands it to the metric's reader."""
+    shutil.copytree(CHIP, tmp_path / "benchmarks" / "chip",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = {p: (tmp_path / p).read_bytes() for p in
+              [os.path.relpath(os.path.join(d, f), tmp_path)
+               for d, _, fs in os.walk(tmp_path) for f in fs]}
+    b = bench()
+    chip = tmp_path / "benchmarks" / "chip"
+    family = (chip / "families" / "dense.py").read_text() + DENSE2
+    (chip / "families" / "dense2.py").write_text(
+        family + (DENSE2_OFF if off else ""))
+    conf = json.loads((chip / "configs" / "danube-4b.json").read_text())
+    conf["name"] = "danube-4b-dense2"
+    conf["tenants"][0]["family"] = "dense2"
+    (chip / "configs" / "danube-4b-dense2.json").write_text(json.dumps(conf))
+    (chip / "metrics" / "dense2_family.py").write_text(
+        "import harness\n\n\ndef read(r):\n"
+        "    want = harness.load_family('dense2')\n"
+        "    return 1.0 if r.families and all(\n"
+        "        f is want for f in r.families.values()) else 0.0\n")
+    b["configs"].append(dict(b["configs"][0], name="danube-4b-dense2",
+                             file="benchmarks/chip/configs/"
+                                  "danube-4b-dense2.json"))
+    b["workloads"].append({"name": "danube-4b.dense2-chat",
+                           "config": "danube-4b-dense2",
+                           "traffic": "chat-fits", "chips": 1,
+                           "why": "a model family added as files only"})
+    b["per_layer"].append({"name": "dense2_family", "unit": "count",
+                           "better": "higher", "source": "program_counter",
+                           "layer": "model step", "moves": "tokens_per_s",
+                           "workloads": ["danube-4b.dense2-chat"]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(b))
+    for p, data in before.items():
+        assert (tmp_path / p).read_bytes() == data, p
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(ROOT, "src"))
+    p = subprocess.run(
+        [sys.executable, "-c", FAMILY_RUN, str(tmp_path), str(int(not off))],
+        env=env, capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0, p.stderr[-4000:]
+    res = json.loads(p.stdout.strip().splitlines()[-1])
+    assert set(res) >= {"correct", "attempted", "failed", "metrics",
+                        "device"}
+    assert list(res)[-1] == "checks"
+    gap = res["checks"]["max_logit_gap"]
+    control = json.loads(p.stderr.split("control ")[-1].splitlines()[0])
+    if off:
+        assert res["correct"] is False
+        # the family's own gaps: the served tokens are its weights' argmax,
+        # so each reads its offset of 1.0 and little more
+        assert 1.0 <= gap["value"] < 1.0 + gap["limit"]
+        assert control["control_max_logit_gap"] == 7.0
+    else:
+        assert res["correct"] is True
+        assert res["metrics"] == {"dense2_family": {"value": 1.0,
+                                                    "unit": "count"}}
+        assert control["control_tokens"] == res["checks"][
+            "sampled_tokens"]["value"]
