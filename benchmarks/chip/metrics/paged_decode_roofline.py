@@ -1,7 +1,11 @@
 """Kernel (kernels/paged_attention, decode): the least time the chip
 could take for the bytes and FLOPs the algorithm needs (live pages of K and
 V of the batch, q and the output; flops.py) over the kernel's device time
-in the trace, in percent. Memory bandwidth bounds it at these shapes."""
+in the trace, in percent. Memory bandwidth bounds it at these shapes.
+
+Dense (grouped-query attention) families only: it counts K and V bytes from
+``head_dim`` and ``num_key_value_heads``. A cell of another attention lists
+a roofline reader of its own, not this one."""
 
 from flops import paged_decode_bytes, paged_decode_flops, roofline_seconds
 from tracered import select

@@ -1,7 +1,7 @@
-"""CPU rehearsal of the chip benchmark: name resolution, seed-stable
-traffic, a well-formed result line from a tiny run through the test-only
-entry, the real entry's refusal without a TPU, and a cell added as files
-only."""
+"""CPU rehearsal of the chip benchmark: name resolution and the rule by
+which a cell gets its per-layer metrics, seed-stable traffic, a well-formed
+result line from a tiny run through the test-only entry, the real entry's
+refusal without a TPU, and a cell added as files only."""
 from __future__ import annotations
 
 import copy
@@ -54,19 +54,111 @@ def tiny(config, mix):
     return config, mix
 
 
+def check_contract(bench: dict) -> list:
+    """Every breach, in ``bench``, of the rule by which the harness measures
+    a cell: by the per-layer metrics whose ``workloads`` list names it, or
+    that have no list, and by no others. Files are found through the
+    ``harness`` beside this file, so a copy of the benchmark checks the
+    copy's files."""
+    out = []
+    cells = {w["name"] for w in bench["workloads"]}
+    for m in bench["per_layer"]:
+        listed = m.get("workloads")
+        if listed is None:
+            continue
+        out += [f"{m['name']}: workloads names {w!r}, which is no cell"
+                for w in listed if w not in cells]
+        if not cells & set(listed):
+            out.append(f"{m['name']}: no cell reads it")
+    confs = {c["name"]: c for c in bench["configs"]}
+    for w in bench["workloads"]:
+        name, found = w["name"], len(out)
+        conf = confs.get(w["config"])
+        if conf is None:
+            out.append(f"{name}: no configuration {w['config']!r}")
+        elif not os.path.isfile(os.path.join(harness.ROOT, conf["file"])):
+            out.append(f"{name}: no configuration file {conf['file']}")
+        if not os.path.isfile(os.path.join(harness.HERE, "traffic",
+                                           w["traffic"] + ".json")):
+            out.append(f"{name}: no traffic file {w['traffic']}.json")
+        if len(out) > found:
+            continue
+        try:
+            info = harness.resolve(name, bench)
+        except SystemExit as e:         # a model family missing or partial
+            out.append(f"{name}: {e}")
+            continue
+        got = sorted(m["name"] for m in info["per_layer"])
+        want = sorted(m["name"] for m in bench["per_layer"]
+                      if "workloads" not in m or name in m["workloads"])
+        if got != want:
+            out.append(f"{name}: resolves {got}, but {want} name it")
+        if not got:
+            out.append(f"{name}: resolves no per-layer metric")
+        e2e = {m["name"] for m in info["end_to_end"]}
+        lacks = {"setup_s", "tokens_per_s"} - e2e
+        if lacks:
+            out.append(f"{name}: no end-to-end {sorted(lacks)}")
+        for m in info["per_layer"]:
+            if m["moves"] not in e2e:
+                out.append(f"{name}: {m['name']} moves {m['moves']!r}, "
+                           f"which is no end-to-end metric of the cell")
+            try:
+                read = harness.load_reader(m["name"])
+            except (OSError, ImportError, SyntaxError, AttributeError) as e:
+                out.append(f"{name}: reader of {m['name']} does not load: "
+                           f"{e!r}")
+                continue
+            if not callable(read):
+                out.append(f"{name}: reader of {m['name']} has no read()")
+    return out
+
+
 def test_every_cell_resolves_by_name():
     b = bench()
     assert b["paths"] == ["benchmarks/chip"]
+    assert check_contract(b) == []
     for w in b["workloads"]:
         info = harness.resolve(w["name"], b)
         assert info["config"]["tenants"] and info["mix"]["streams"]
-        names = {m["name"] for m in info["per_layer"]}
-        assert names == {m["name"] for m in b["per_layer"]}
-        for m in info["per_layer"]:
-            assert callable(harness.load_reader(m["name"]))
-        e2e = {m["name"] for m in info["end_to_end"]}
-        assert {"setup_s", "tokens_per_s"} <= e2e
-        assert {m["moves"] for m in info["per_layer"]} <= e2e
+
+
+def unread(b):
+    """No metric lists the first cell any more."""
+    for m in b["per_layer"]:
+        m["workloads"] = [w for w in m["workloads"]
+                          if w != b["workloads"][0]["name"]]
+
+
+FAULTS = {
+    "metric names no cell": (
+        lambda b: b["per_layer"][0]["workloads"].append("no-such.cell"),
+        "workloads names 'no-such.cell', which is no cell"),
+    "metric no cell reads": (
+        lambda b: b["per_layer"][1].update(workloads=[]),
+        "no cell reads it"),
+    "reader file missing": (
+        lambda b: b["per_layer"].append(
+            dict(b["per_layer"][2], name="no_such_reader")),
+        "reader of no_such_reader does not load"),
+    "moves no end-to-end metric": (
+        lambda b: b["per_layer"][3].update(moves="tbt_p95_s"),
+        "moves 'tbt_p95_s', which is no end-to-end metric"),
+    "cell reads no metric": (
+        unread, "resolves no per-layer metric"),
+    "traffic file missing": (
+        lambda b: b["workloads"][1].update(traffic="no-such-mix"),
+        "no traffic file no-such-mix.json"),
+}
+
+
+@pytest.mark.parametrize("fault", list(FAULTS))
+def test_the_contract_check_reports_each_fault(fault):
+    b = bench()
+    plant, says = FAULTS[fault]
+    plant(b)
+    breaches = check_contract(b)
+    assert any(says in line for line in breaches), breaches
 
 
 def test_configs_name_their_source_and_cuts():
@@ -164,15 +256,19 @@ def test_run_outside_the_repository_fails(tmp_path):
 
 ADDED = r'''
 import json, os, sys
-sys.path.insert(0, os.path.join(sys.argv[1], "benchmarks", "chip"))
+chip = os.path.join(sys.argv[1], "benchmarks", "chip")
+sys.path[:0] = [chip, os.path.join(chip, "tests")]
 import gen, harness
+from test_chip_rehearsal import check_contract
 info = harness.resolve("danube-4b.new-mix")
 vocab = {s["tenant"]: 32000 for s in info["mix"]["streams"]}
 items = gen.generate(info["mix"], 3, 10, vocab)
 reader = harness.load_reader("new_metric")
 print(json.dumps({"config": info["config"]["name"], "n": len(items),
                   "metrics": [m["name"] for m in info["per_layer"]],
-                  "value": reader(None)}))
+                  "value": reader(None),
+                  "contract": check_contract(
+                      harness.load_json(sys.argv[1], "BENCHMARK.json"))}))
 '''
 
 
@@ -202,7 +298,7 @@ def test_a_cell_config_and_metric_are_added_as_files_only(tmp_path):
                            "chips": 1, "why": "added as files only"})
     b["per_layer"].append({"name": "new_metric", "unit": "%",
                            "better": "higher", "source": "program_counter",
-                           "layer": "model step", "moves": "tbt_p95_s",
+                           "layer": "model step", "moves": "tokens_per_s",
                            "workloads": ["danube-4b.new-mix"]})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(b))
     for p, data in before.items():
@@ -213,7 +309,7 @@ def test_a_cell_config_and_metric_are_added_as_files_only(tmp_path):
     assert p.returncode == 0, p.stderr
     res = json.loads(p.stdout.strip().splitlines()[-1])
     assert res == {"config": "danube-4b-copy", "n": res["n"],
-                   "metrics": ["new_metric"], "value": 42.0}
+                   "metrics": ["new_metric"], "value": 42.0, "contract": []}
     assert res["n"] > 0
 
 
@@ -241,7 +337,9 @@ import json, os, sys, time
 chip = os.path.join(sys.argv[1], "benchmarks", "chip")
 sys.path[:0] = [chip, os.path.join(chip, "tests")]
 import calibrate, harness
-from test_chip_rehearsal import BIG_SEED, tiny
+from test_chip_rehearsal import BIG_SEED, check_contract, tiny
+print("contract " + json.dumps(check_contract(
+    harness.load_json(sys.argv[1], "BENCHMARK.json"))), file=sys.stderr)
 compare, control = harness.compare, {}
 
 def both(config, seed, chosen):
@@ -309,6 +407,7 @@ def test_a_model_family_is_added_as_files_only(tmp_path, off):
                         "device"}
     assert list(res)[-1] == "checks"
     gap = res["checks"]["max_logit_gap"]
+    assert json.loads(p.stderr.split("contract ")[-1].splitlines()[0]) == []
     control = json.loads(p.stderr.split("control ")[-1].splitlines()[0])
     if off:
         assert res["correct"] is False

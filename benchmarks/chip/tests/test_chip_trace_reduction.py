@@ -74,7 +74,8 @@ def readings(trace, calls, out_tokens=100):
             "num_hidden_layers": 24, "num_attention_heads": 32,
             "num_key_value_heads": 8, "head_dim": 120, "vocab_size": 32000}
     return harness.Readings(rec, {"out_tokens": out_tokens}, trace,
-                            {"t": arch}, 16, peaks.peaks("TPU v5 lite"))
+                            {"t": arch}, 16, peaks.peaks("TPU v5 lite"),
+                            {"t": harness.load_family("dense")})
 
 
 def test_readers_on_the_hand_made_trace():
@@ -96,6 +97,19 @@ def test_readers_on_the_hand_made_trace():
         arch, [15, 100]) / (0.02 * 197e12))
     assert harness.load_reader("remapped_step_share")(r) == 100.0
     assert harness.load_reader("host_bytes_per_token")(r) == 0.1
+
+
+def test_decode_mfu_reads_flops_through_the_tenant_family():
+    """Two decode calls on a hand-made module table: the reading through
+    the dense family is the one ``flops.decode_step_flops`` gives, to the
+    last digit."""
+    runs = [0.0213, 0.0197]
+    calls = [{"tenant": "t", "m": 0, "host_bytes": 0, "ctx": [15, 100]},
+             {"tenant": "t", "m": 0, "host_bytes": 0, "ctx": [16, 101, 7]}]
+    r = readings({"modules": {"jit_step_decode(7)": runs}}, calls)
+    want = 100.0 * sum(flops.decode_step_flops(r.archs["t"], c["ctx"])
+                       for c in calls) / (sum(runs) * r.peak["bf16_flops"])
+    assert harness.load_reader("decode_mfu")(r) == want
 
 
 def test_readers_report_nothing_without_a_trace():
